@@ -1,2 +1,2 @@
-from .store import AsyncCheckpointer, CheckpointStore
-__all__ = ["AsyncCheckpointer", "CheckpointStore"]
+from .store import AsyncCheckpointer, CheckpointError, CheckpointStore
+__all__ = ["AsyncCheckpointer", "CheckpointError", "CheckpointStore"]

@@ -116,6 +116,10 @@ class CheckpointStore:
             shutil.rmtree(self.dir / f"step_{s}", ignore_errors=True)
 
 
+class CheckpointError(RuntimeError):
+    """A submitted checkpoint failed to save or did not finish in time."""
+
+
 class AsyncCheckpointer:
     """Background checkpoint writer fed by transactional snapshots.
 
@@ -167,19 +171,33 @@ class AsyncCheckpointer:
                     self._busy = False
                     self._idle.notify_all()
 
-    def drain(self, timeout: float = 60.0) -> None:
+    def drain(self, timeout: float = 600.0) -> None:
         """Block until every submitted snapshot is fully on disk — i.e. no
         job is pending AND no save is in flight (a drain that returns while
         the last save is mid-write lets callers observe the previous
-        LATEST pointer)."""
+        LATEST pointer).
+
+        Raises :class:`CheckpointError` if the wait times out or any save
+        has failed, so a run never ends looking healthy without its
+        checkpoints.
+        """
         self._wake.set()
         with self._lock:
-            self._idle.wait_for(
+            idle = self._idle.wait_for(
                 lambda: self._pending is None and not self._busy,
                 timeout=timeout)
+        if not idle:
+            raise CheckpointError(
+                f"checkpoint not on disk after {timeout:.0f}s")
+        if self.errors:
+            raise CheckpointError(
+                f"{len(self.errors)} checkpoint save(s) failed: "
+                f"{self.errors[0]}")
 
     def stop(self) -> None:
-        self.drain()
-        self._stop.set()
-        self._wake.set()
-        self._thread.join(timeout=10.0)
+        try:
+            self.drain()
+        finally:
+            self._stop.set()
+            self._wake.set()
+            self._thread.join(timeout=10.0)

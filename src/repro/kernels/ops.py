@@ -2,10 +2,10 @@
 
 Selection policy (``impl`` argument, default ``"auto"``):
 
-* ``"auto"``    — Pallas on TPU backends; the pure-jnp reference path
-  elsewhere (this CPU container lowers/compiles the jnp path; the Pallas
-  path is exercised in tests via ``interpret=True``).
-* ``"pallas"``  — force the kernel (uses interpret mode off-TPU).
+* ``"auto"``    — the compiled Pallas kernel on TPU backends; the pure-jnp
+  reference path elsewhere.
+* ``"pallas"``  — force the kernel; off-TPU it runs in interpret mode (how
+  the CPU tests exercise it). On a TPU it is always compiled.
 * ``"ref"``     — force the jnp oracle.
 
 The models only ever import these wrappers, so swapping the execution
@@ -13,8 +13,6 @@ substrate never touches model code.
 """
 from __future__ import annotations
 
-import functools
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -29,9 +27,6 @@ def _on_tpu() -> bool:
 
 def _resolve(impl: str) -> str:
     if impl == "auto":
-        forced = os.environ.get("REPRO_KERNEL_IMPL")
-        if forced:
-            return forced
         return "pallas" if _on_tpu() else "ref"
     return impl
 
@@ -66,8 +61,8 @@ def rwkv6_scan(r, k, v, w, u, state, *, impl: str = "auto",
     impl = _resolve(impl)
     if impl == "pallas":
         from .rwkv6_kernel import rwkv6_scan_pallas
-        return rwkv6_scan_pallas(r, k, v, w, u, state, block_t=block_t,
-                                 interpret=not _on_tpu())
+        return rwkv6_scan_pallas(r, k, v, w, u, state, block_t,
+                                 not _on_tpu())
     return kref.rwkv6_scan_ref(r, k, v, w, u, state)
 
 
@@ -80,7 +75,6 @@ def rglru_scan(x, a_log, gate_r, gate_i, h0, *, impl: str = "auto",
     impl = _resolve(impl)
     if impl == "pallas":
         from .rglru_kernel import rglru_scan_pallas
-        return rglru_scan_pallas(x, a_log, gate_r, gate_i, h0,
-                                 block_t=block_t, block_w=block_w,
-                                 interpret=not _on_tpu())
+        return rglru_scan_pallas(x, a_log, gate_r, gate_i, h0, block_t,
+                                 block_w, not _on_tpu())
     return kref.rglru_scan_ref(x, a_log, gate_r, gate_i, h0)

@@ -5,8 +5,10 @@ scratch across time chunks: grid ``(B·H, nt)`` with the time dimension
 innermost/sequential. Each grid step streams one ``[block_t, hd]`` tile of
 r/k/v/w from HBM into VMEM and walks it with a ``fori_loop`` of rank-1
 updates (VPU work — the recurrence is elementwise/outer-product shaped, so
-the MXU has nothing to chew on; the chunked matmul reformulation is the
-documented follow-up optimization in EXPERIMENTS.md §Perf).
+the MXU has nothing to chew on; a chunked matmul reformulation would give
+it some). The tiles are fp32 and ``u`` rides as ``[B·H, 1, hd]``, so every
+block's last two dims are tile-aligned or whole, as the TPU compiler
+requires.
 
 The initial state is read once at ``ti == 0`` and the final state written
 at ``ti == nt-1``, so checkpointed decode (long_500k) round-trips state
@@ -22,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import ref as kref
+
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref,
                 state_s, *, block_t: int, nt: int):
@@ -31,7 +35,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref,
     def _load_state():
         state_s[...] = s0_ref[0].astype(jnp.float32)
 
-    u = u_ref[0].astype(jnp.float32)                    # [hd]
+    u = u_ref[0, 0].astype(jnp.float32)                 # [hd]
 
     def step(t, _):
         rt = r_ref[0, t, :].astype(jnp.float32)         # [hd]
@@ -52,16 +56,38 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref,
         sT_ref[0] = state_s[...]
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
 def rwkv6_scan_pallas(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
-                      u: jax.Array, state: jax.Array, *,
-                      block_t: int = 64,
+                      u: jax.Array, state: jax.Array, block_t: int = 64,
                       interpret: bool = False
                       ) -> Tuple[jax.Array, jax.Array]:
-    """r,k,v,w: [B,T,H,hd]; u: [H,hd]; state: [B,H,hd,hd] -> (y fp32, state fp32)."""
+    """r,k,v,w: [B,T,H,hd]; u: [H,hd]; state: [B,H,hd,hd] -> (y fp32, state fp32).
+
+    Differentiable: the backward pass is the VJP of ``ref.rwkv6_scan_ref``.
+    """
+    return _scan(r, k, v, w, u, state, block_t, interpret)
+
+
+def _scan_fwd(r, k, v, w, u, state, block_t, interpret):
+    return (_scan(r, k, v, w, u, state, block_t, interpret),
+            (r, k, v, w, u, state))
+
+
+def _scan_bwd(block_t, interpret, res, g):
+    return jax.vjp(kref.rwkv6_scan_ref, *res)[1](g)
+
+
+rwkv6_scan_pallas.defvjp(_scan_fwd, _scan_bwd)
+
+
+def _scan(r, k, v, w, u, state, block_t, interpret):
     B, T, H, hd = r.shape
     block_t = min(block_t, T)
     pad_t = (-T) % block_t
-    fold = lambda a: a.transpose(0, 2, 1, 3).reshape(B * H, T, hd)
+    # fp32 tiles: the kernel reads one row per step at a dynamic offset,
+    # which Mosaic cannot prove aligned for packed (bf16) rows
+    fold = lambda a: a.astype(jnp.float32).transpose(0, 2, 1, 3).reshape(
+        B * H, T, hd)
     rf, kf, vf, wf = map(fold, (r, k, v, w))
     if pad_t:
         # pad with w=1, k=0: state is untouched by padded steps
@@ -70,12 +96,12 @@ def rwkv6_scan_pallas(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
         wf = jnp.pad(wf, zpad, constant_values=1.0)
     Tp = T + pad_t
     nt = Tp // block_t
-    uf = jnp.tile(u, (B, 1))                            # [B*H, hd]
+    # [B*H, 1, hd]: a block's last two dims then equal the array's
+    uf = jnp.tile(u, (B, 1))[:, None, :]
     sf = state.reshape(B * H, hd, hd)
 
     kernel = functools.partial(_wkv_kernel, block_t=block_t, nt=nt)
     seq_map = lambda bh, ti: (bh, ti, 0)
-    bh_map = lambda bh, ti: (bh, 0)
     st_map = lambda bh, ti: (bh, 0, 0)
 
     y, sT = pl.pallas_call(
@@ -86,7 +112,7 @@ def rwkv6_scan_pallas(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
             pl.BlockSpec((1, block_t, hd), seq_map),    # k
             pl.BlockSpec((1, block_t, hd), seq_map),    # v
             pl.BlockSpec((1, block_t, hd), seq_map),    # w
-            pl.BlockSpec((1, hd), bh_map),              # u
+            pl.BlockSpec((1, 1, hd), st_map),           # u
             pl.BlockSpec((1, hd, hd), st_map),          # s0
         ],
         out_specs=[

@@ -1,12 +1,10 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                           " --xla_force_host_platform_device_count=512").strip()
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
-MUST be run as a fresh process (``python -m repro.launch.dryrun``): the
-XLA_FLAGS line above executes before any jax import so the host platform
-exposes 512 placeholder devices for the production meshes.
+Run it as a fresh process (``python -m repro.launch.dryrun``): ``main()``
+adds ``--xla_force_host_platform_device_count=512`` to ``XLA_FLAGS`` before
+jax initializes its backend, so the host platform exposes 512 placeholder
+devices for the production meshes. In-process callers of ``run_cell`` set
+``XLA_FLAGS`` themselves before touching jax.
 
 For each cell this:
   1. builds the arch's Backbone with the production PartitionPlan,
@@ -20,6 +18,7 @@ and recorded as {"skipped": reason}.
 """
 import argparse
 import json
+import os
 import time
 import traceback
 from pathlib import Path
@@ -31,10 +30,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.launch import hlocost
 from repro.launch import roofline as rl
-from repro.launch.mesh import dp_axes, make_production_mesh, tp_size
+from repro.launch.mesh import make_production_mesh, tp_size
 from repro.launch.shardings import (batch_shardings, cache_shardings,
-                                    make_param_gatherer, make_sharder,
-                                    param_shardings)
+                                    effective_dp, sharded_backbone,
+                                    train_state_shardings)
 from repro.models import SHAPES, Backbone, PartitionPlan, get_config
 from repro.models.config import ARCH_NAMES, ShapeConfig
 from repro.optim import adamw
@@ -64,28 +63,11 @@ def _spec_like(tree, shardings):
 def build_cell(arch: str, shape: ShapeConfig, mesh, *,
                settings: StepSettings):
     """Returns (jitted_fn, example_args_specs)."""
-    from repro.launch.shardings import effective_dp, full_dp_active
     cfg = get_config(arch)
-    fdp = full_dp_active(cfg, mesh, shape.global_batch)
-    plan = PartitionPlan(tp=1 if fdp else tp_size(mesh))
-    dp = effective_dp(cfg, mesh, shape.global_batch)
     serve = shape.kind != "train"
-    gatherer = (make_param_gatherer(cfg, mesh, full_dp=fdp)
-                if (settings.gather_weights and settings.zero3
-                    and not serve) else None)
-    bb = Backbone(cfg, plan,
-                  compute_dtype=jnp.bfloat16,
-                  param_dtype=jnp.bfloat16 if serve else jnp.float32,
-                  remat=settings.remat and not serve,
-                  remat_policy=settings.remat_policy,
-                  sharder=make_sharder(cfg, mesh,
-                                       batch_sharded=shape.global_batch > 1,
-                                       global_batch=shape.global_batch),
-                  param_gather=gatherer,
-                  moe_impl="ep" if settings.moe_ep else "gspmd",
-                  mesh=mesh,
-                  dp_axes=dp if shape.global_batch > 1 else ())
-    p_sh = param_shardings(bb, mesh, zero3=settings.zero3, full_dp=fdp)
+    bb, p_sh = sharded_backbone(cfg, mesh, shape.global_batch, settings,
+                                serve=serve)
+    dp = effective_dp(cfg, mesh, shape.global_batch)
     B, S = shape.global_batch, shape.seq_len
     bsh = batch_shardings(cfg, shape, mesh, batch_sharded=B > 1)
     dpspec = (dp or None) if B > 1 else None
@@ -94,13 +76,7 @@ def build_cell(arch: str, shape: ShapeConfig, mesh, *,
         opt_cfg = adamw.AdamWConfig()
         step = make_train_step(bb, opt_cfg, settings)
         state_specs = train_state_specs(bb, settings)
-        state_sh = {
-            "params": p_sh,
-            "opt": {"step": NamedSharding(mesh, P()),
-                    "m": p_sh, "v": p_sh},
-        }
-        if settings.compress_grads:
-            state_sh["error"] = p_sh
+        state_sh = train_state_shardings(p_sh, mesh, settings)
         batch = {
             "tokens": jax.ShapeDtypeStruct((B, S), jnp.int32),
             "labels": jax.ShapeDtypeStruct((B, S), jnp.int32),
@@ -161,11 +137,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *,
         t_compile = time.time() - t0 - t_lower
 
     mem = compiled.memory_analysis()
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):     # older jax: [dict] per device
-        cost = cost[0] if cost else {}
-    elif cost is None:
-        cost = {}
+    cost = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     totals = hlocost.analyze(hlo)       # trip-count-aware (source of record)
 
@@ -174,7 +146,8 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *,
     bb = Backbone(cfg, plan)
     tokens = shape.global_batch * (shape.seq_len if shape.kind != "decode" else 1)
     mflops = rl.model_flops(bb, shape.kind, tokens)
-    terms = rl.derive_terms_from_totals(totals, mflops, n_chips)
+    terms = rl.derive_terms_from_totals(totals, mflops, n_chips,
+                                        rl.chip_peaks(rl.DRYRUN_TARGET))
 
     result.update({
         "lower_s": round(t_lower, 2),
@@ -205,6 +178,9 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *,
 
 
 def main() -> None:
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + " --xla_force_host_platform_device_count=512").strip()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="all",
                     help="arch id or 'all'")

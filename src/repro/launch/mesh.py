@@ -8,23 +8,35 @@ Single pod: ``(data=16, model=16)`` — 256 chips (one v5e pod).
 Multi-pod:  ``(pod=2, data=16, model=16)`` — 512 chips across DCN; the
 ``pod`` axis carries pure data parallelism (gradient all-reduce over DCN),
 ``data`` carries ZeRO sharding, ``model`` carries TP/EP.
+
+Every axis is ``Auto``: the sharding rules (``launch.shardings``) steer
+GSPMD with ``with_sharding_constraint``, which only accepts Auto axes
+(``jax.make_mesh`` defaults to Explicit).
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices=None) -> jax.sharding.Mesh:
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(*, dp: int = 1, tp: int = 1) -> jax.sharding.Mesh:
-    """Small mesh for local smoke runs (defaults to the single CPU device)."""
-    return jax.make_mesh((dp, tp), ("data", "model"))
+    """``(data=dp, model=tp)`` over this host's devices."""
+    return make_mesh((dp, tp), ("data", "model"))
 
 
 def dp_axes(mesh: jax.sharding.Mesh) -> Tuple[str, ...]:

@@ -1,12 +1,11 @@
 """Roofline-term derivation from compiled dry-run artifacts.
 
 Three terms per (arch × shape × mesh) cell, all in seconds-per-step on the
-TARGET hardware (TPU v5e-class constants; this container is CPU-only so we
-derive from the compiled module, never from wall time):
+target chip, derived from the compiled module (never from wall time):
 
-    compute    = HLO_FLOPs_per_device / PEAK_FLOPS
-    memory     = HLO_bytes_per_device / HBM_BW
-    collective = collective_bytes_per_device / ICI_BW
+    compute    = HLO_FLOPs_per_device / peak FLOP/s
+    memory     = HLO_bytes_per_device / HBM bytes/s
+    collective = collective_bytes_per_device / ICI bytes/s per link
 
 ``cost_analysis()`` of a GSPMD-partitioned executable reports the per-device
 module, so no extra division by chip count is applied. Collective bytes are
@@ -23,10 +22,33 @@ from collections import defaultdict
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
-# ---- hardware constants (TPU v5e-class, per chip) --------------------------
-PEAK_FLOPS = 197e12          # bf16 FLOP/s
-HBM_BW = 819e9               # bytes/s
-ICI_BW = 50e9                # bytes/s per link (assignment constant)
+# ---- per-chip peaks, keyed by jax's ``device_kind`` -------------------------
+@dataclass(frozen=True)
+class ChipPeaks:
+    flops: float     # dense bf16 FLOP/s
+    hbm_bw: float    # HBM bytes/s
+    ici_bw: float    # inter-chip bytes/s per link
+
+
+# Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM
+# at 819 GB/s, 1,600 Gbit/s of inter-chip interconnect (4 links, 50 GB/s
+# each).
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+# The chip the production-mesh dry-run compiles for (its placeholder host
+# devices report no TPU kind of their own).
+DRYRUN_TARGET = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of one chip; a kind with no published entry is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
 
 _DTYPE_BYTES = {
     "f64": 8, "s64": 8, "u64": 8,
@@ -160,6 +182,7 @@ class RooflineTerms:
     model_flops: float          # global useful FLOPs per step
     hlo_flops: float            # per-device compiled FLOPs
     useful_ratio: float         # (model_flops / chips) / hlo_flops
+    peaks: ChipPeaks
     n_chips: int = 1
 
     @property
@@ -172,7 +195,7 @@ class RooflineTerms:
     def roofline_fraction(self) -> float:
         """Useful-compute time over the binding term: time the chip would
         spend on MODEL_FLOPS at peak, divided by the dominant-term time."""
-        useful_s = self.model_flops / self.n_chips / PEAK_FLOPS
+        useful_s = self.model_flops / self.n_chips / self.peaks.flops
         bound = max(self.compute_s, self.memory_s, self.collective_s)
         return useful_s / bound if bound > 0 else 0.0
 
@@ -190,33 +213,36 @@ class RooflineTerms:
 
 
 def derive_terms(cost: Dict[str, float], coll: CollectiveStats,
-                 mflops: float, n_chips: int) -> RooflineTerms:
+                 mflops: float, n_chips: int,
+                 peaks: ChipPeaks) -> RooflineTerms:
     """cost = compiled.cost_analysis() of the partitioned (per-device) module."""
     hlo_flops = float(cost.get("flops", 0.0))
     hlo_bytes = float(cost.get("bytes accessed", 0.0))
     per_chip_useful = mflops / n_chips
     return RooflineTerms(
-        compute_s=hlo_flops / PEAK_FLOPS,
-        memory_s=hlo_bytes / HBM_BW,
-        collective_s=coll.bytes_total / ICI_BW,
+        compute_s=hlo_flops / peaks.flops,
+        memory_s=hlo_bytes / peaks.hbm_bw,
+        collective_s=coll.bytes_total / peaks.ici_bw,
         model_flops=mflops,
         hlo_flops=hlo_flops,
         useful_ratio=(per_chip_useful / hlo_flops) if hlo_flops else 0.0,
+        peaks=peaks,
         n_chips=n_chips,
     )
 
 
-def derive_terms_from_totals(totals, mflops: float, n_chips: int
-                             ) -> RooflineTerms:
+def derive_terms_from_totals(totals, mflops: float, n_chips: int,
+                             peaks: ChipPeaks) -> RooflineTerms:
     """Terms from the trip-count-aware HLO cost model (launch.hlocost) —
     the source of record for §Roofline (cost_analysis undercounts loops)."""
     per_chip_useful = mflops / n_chips
     return RooflineTerms(
-        compute_s=totals.flops / PEAK_FLOPS,
-        memory_s=totals.bytes / HBM_BW,
-        collective_s=totals.collective_bytes / ICI_BW,
+        compute_s=totals.flops / peaks.flops,
+        memory_s=totals.bytes / peaks.hbm_bw,
+        collective_s=totals.collective_bytes / peaks.ici_bw,
         model_flops=mflops,
         hlo_flops=totals.flops,
         useful_ratio=(per_chip_useful / totals.flops) if totals.flops else 0.0,
+        peaks=peaks,
         n_chips=n_chips,
     )

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Backbone, get_config, reduced
 from repro.runtime.serve_loop import Request, Server
 
@@ -26,12 +27,13 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=8)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
-    bb = Backbone(cfg, compute_dtype=jnp.float32, remat=False)
-    params = bb.init(jax.random.PRNGKey(0))
+    bb = Backbone(cfg, param_dtype=jnp.bfloat16, remat=False)
+    params = jax.jit(bb.init)(jax.random.PRNGKey(0))
     srv = Server(bb, params, slots=args.slots, ctx=args.ctx)
 
     rng = np.random.default_rng(0)

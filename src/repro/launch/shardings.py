@@ -28,6 +28,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models.backbone import Backbone
 from repro.models.config import ModelConfig, ShapeConfig
+from repro.models.partition import PartitionPlan
 from .mesh import dp_axes, tp_size
 
 Params = Any
@@ -209,6 +210,46 @@ def cache_shardings(bb: Backbone, mesh: Mesh, B: int) -> Params:
         return NamedSharding(mesh, P(*([None] * len(shp))))
 
     return jax.tree_util.tree_map_with_path(spec_for, cache_shape)
+
+
+def sharded_backbone(cfg: ModelConfig, mesh: Mesh, global_batch: int,
+                     settings, *, serve: bool = False
+                     ) -> Tuple[Backbone, Params]:
+    """The Backbone wired for ``mesh`` and its parameter shardings.
+
+    Training keeps fp32 master parameters and computes in bf16; serving
+    holds bf16 parameters. ``settings`` is a ``runtime.steps.StepSettings``.
+    """
+    fdp = full_dp_active(cfg, mesh, global_batch)
+    plan = PartitionPlan(tp=1 if fdp else tp_size(mesh))
+    dp = effective_dp(cfg, mesh, global_batch)
+    gatherer = (make_param_gatherer(cfg, mesh, full_dp=fdp)
+                if (settings.gather_weights and settings.zero3
+                    and not serve) else None)
+    bb = Backbone(cfg, plan,
+                  compute_dtype=jnp.bfloat16,
+                  param_dtype=jnp.bfloat16 if serve else jnp.float32,
+                  remat=settings.remat and not serve,
+                  remat_policy=settings.remat_policy,
+                  sharder=make_sharder(cfg, mesh,
+                                       batch_sharded=global_batch > 1,
+                                       global_batch=global_batch),
+                  param_gather=gatherer,
+                  moe_impl="ep" if settings.moe_ep else "gspmd",
+                  mesh=mesh,
+                  dp_axes=dp if global_batch > 1 else ())
+    return bb, param_shardings(bb, mesh, zero3=settings.zero3, full_dp=fdp)
+
+
+def train_state_shardings(p_sh: Params, mesh: Mesh, settings) -> Params:
+    """Shardings of ``runtime.steps.init_train_state``'s tree: the
+    optimizer moments (and error feedback) follow their parameters."""
+    state_sh = {"params": p_sh,
+                "opt": {"step": NamedSharding(mesh, P()),
+                        "m": p_sh, "v": p_sh}}
+    if settings.compress_grads:
+        state_sh["error"] = p_sh
+    return state_sh
 
 
 def make_param_gatherer(cfg: ModelConfig, mesh: Mesh, *,

@@ -1,11 +1,12 @@
 """Attention: GQA with RoPE, windows, soft-capping; flash-style jnp fallback.
 
-The training/prefill path is a two-level-chunked online-softmax attention
-(``flash_attention_jnp``) — the same algorithm as the Pallas kernel in
-``repro.kernels.flash_attention`` but expressed with ``lax.scan`` so that it
-lowers on any backend with O(chunk) memory. The Pallas kernel is selected on
-TPU via ``repro.kernels.ops.flash_attention`` (validated against this
-implementation's oracle in tests).
+Every model path — training, prefill and decode, on every backend — runs
+``flash_attention_jnp``: a two-level-chunked online-softmax attention
+expressed with ``lax.scan`` so that it lowers anywhere with O(chunk) memory,
+with a flash-style custom VJP. The Pallas forward kernel in
+``repro.kernels.flash_attention`` implements the same algorithm; it is
+reached only through ``repro.kernels.ops.flash_attention`` (tests and the
+chip smoke check it against ``kernels.ref``), not from the models.
 
 GQA is computed in grouped form (queries reshaped to [B,S,n_kv,G,hd]) so KV
 heads are never materialized repeated.
@@ -71,8 +72,7 @@ def flash_attention_jnp(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     The backward pass recomputes score blocks chunk-by-chunk (the flash
     backward algorithm) instead of letting autodiff stack per-chunk
-    residuals across the scan — on TPU both directions are Pallas kernels
-    whose block buffers never leave VMEM.
+    residuals across the scan.
     """
     if q_positions is None:
         q_positions = jnp.arange(q.shape[1], dtype=jnp.int32)
@@ -150,9 +150,9 @@ def _flash_fwd_impl(q, k, v, q_positions, kv_positions, causal, window,
         lse = m + jnp.log(jnp.maximum(l, 1e-30))              # [B,Hkv,G,Cq]
         return None, (out.transpose(0, 3, 1, 2, 4), lse)
 
-    # vmem_kernel scope: on TPU this whole loop nest is one Pallas kernel
-    # (repro.kernels.flash_attention) whose chunk buffers never leave VMEM;
-    # the HLO cost model charges bytes for kernel I/O only (see hlocost).
+    # vmem_kernel scope: the HLO cost model (hlocost) charges this loop nest
+    # bytes for its I/O only, as if it were one fused kernel like
+    # repro.kernels.flash_attention; XLA runs it as the scan it is.
     with jax.named_scope("vmem_kernel_flash"):
         _, (outs, lses) = jax.lax.scan(q_body, None, (qg, qp))
     out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, nq * q_chunk, Hq, hd)

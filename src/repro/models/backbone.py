@@ -9,6 +9,7 @@ enc/dec split) are homogeneous *within* a scan body by construction.
 Entry points:
 
 * ``loss_fn(params, batch)``      — training loss (causal LM / enc-dec LM)
+* ``forward(params, batch)``      — logits at every position (+ MoE aux)
 * ``prefill(params, batch)``      — run the context, return last-token logits
   plus a filled decode cache
 * ``decode_step(params, cache, tokens)`` — one token with a KV/state cache
@@ -43,13 +44,26 @@ def _no_shard(x: jax.Array, name: str) -> jax.Array:
     return x
 
 
+def _ring_fill(vals: jax.Array, C: int, axis: int, fill) -> jax.Array:
+    """A ring buffer of ``C`` slots along ``axis`` holding the last
+    ``min(C, S)`` of ``vals``' ``S`` positions, position ``p`` in slot
+    ``p % C`` and ``fill`` elsewhere. Built by pad and roll, not a scatter:
+    the TPU compiler aborts on the scatter pair it would fuse for k and v."""
+    S = vals.shape[axis]
+    n = min(C, S)
+    vals = jax.lax.slice_in_dim(vals, S - n, S, axis=axis)
+    pad = [(0, 0)] * vals.ndim
+    pad[axis] = (0, C - n)
+    return jnp.roll(jnp.pad(vals, pad, constant_values=fill), (S - n) % C,
+                    axis=axis)
+
+
 class Backbone:
     def __init__(self, cfg: ModelConfig, plan: PartitionPlan = IDENTITY_PLAN,
                  *, compute_dtype=jnp.bfloat16, param_dtype=jnp.float32,
                  remat: bool = True,
                  sharder: Callable[[jax.Array, str], jax.Array] = _no_shard,
                  param_gather: Optional[Callable[[Params], Params]] = None,
-                 attn_impl: str = "auto",
                  moe_impl: str = "gspmd",
                  remat_policy: str = "full",
                  mesh=None, dp_axes: Tuple[str, ...] = ()):
@@ -62,7 +76,6 @@ class Backbone:
         self.remat_policy = remat_policy
         self.shard = sharder
         self.param_gather = param_gather
-        self.attn_impl = attn_impl
         self.moe_impl = moe_impl
         self.mesh = mesh
         self.dp_axes = dp_axes
@@ -463,9 +476,16 @@ class Backbone:
         return x, total_aux
 
     def loss_fn(self, params: Params, batch: Dict[str, jax.Array]) -> jax.Array:
+        logits, aux = self.forward(params, batch)
+        loss = stable_cross_entropy(logits, batch["labels"],
+                                    self.cfg.final_logit_softcap)
+        return loss + AUX_COEF * aux
+
+    def forward(self, params: Params, batch: Dict[str, jax.Array]
+                ) -> Tuple[jax.Array, jax.Array]:
+        """Logits at every position [B, S, Vp] and the MoE aux loss."""
         cfg = self.cfg
         tokens = batch["tokens"]
-        labels = batch["labels"]
         x = self._embed_tokens(params, tokens)
         x = self.shard(x, "act_hidden")
         positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
@@ -474,9 +494,7 @@ class Backbone:
             x, aux = self._run_decoder(params, x, positions, enc_out)
         else:
             x, aux = self._run_groups(params, x, positions)
-        logits = self._logits(params, x)
-        loss = stable_cross_entropy(logits, labels, cfg.final_logit_softcap)
-        return loss + AUX_COEF * aux
+        return self._logits(params, x), aux
 
     # ------------------------------------------------------------------ #
     # Serving: prefill + decode                                           #
@@ -641,17 +659,10 @@ class Backbone:
                         k = apply_rope(k, positions, cfg.rope_theta,
                                        cfg.rotary_pct)
                         C = self.cache_len(kind, ctx)
-                        n = min(C, S)
-                        sel = positions[S - n:]
-                        slots = sel % C
-                        ck = jnp.zeros((B, C, self.KV, self.hd), x.dtype
-                                       ).at[:, slots].set(k[:, S - n:])
                         # v without rope
-                        cv = jnp.zeros((B, C, self.KV, self.hd), x.dtype
-                                       ).at[:, slots].set(v[:, S - n:])
-                        kpos = jnp.full((C,), -1, jnp.int32
-                                        ).at[slots].set(sel)
-                        sub = {"k": ck, "v": cv, "kpos": kpos}
+                        sub = {"k": _ring_fill(k.astype(x.dtype), C, 1, 0),
+                               "v": _ring_fill(v.astype(x.dtype), C, 1, 0),
+                               "kpos": _ring_fill(positions, C, 0, -1)}
                         ekv = None
                         if kind == "dec":
                             Se = enc_out.shape[1]

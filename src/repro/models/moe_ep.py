@@ -125,16 +125,8 @@ def moe_mlp_ep(params: Dict, x: jax.Array, cfg: ModelConfig, mesh: Mesh,
 
     xt = x.reshape(B * S, D)
     tok_spec = P(dp or None, None)
-    # jax >= 0.6 exposes shard_map at top level (check_vma kwarg); older
-    # releases only have the experimental module (check_rep kwarg, inverted
-    # meaning of neither — both just disable replication checking here).
-    if hasattr(jax, "shard_map"):
-        shard_map = functools.partial(jax.shard_map, check_vma=False)
-    else:
-        from jax.experimental.shard_map import shard_map as _sm
-        shard_map = functools.partial(_sm, check_rep=False)
-    y, aux = shard_map(
-        body, mesh=mesh,
+    y, aux = jax.shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(tok_spec,
                   P(None, None),
                   P("model", None, None),

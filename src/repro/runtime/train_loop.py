@@ -5,8 +5,10 @@ The control plane runs on the transactional store (``repro.txstore``):
 * every step commits (params, opt, cursor) as one write transaction —
   readers can never observe a torn step;
 * checkpoints are taken by an irrevocable read-only transaction (snapshot
-  happens asynchronously per paper §2.7) and written by a background
-  thread (``AsyncCheckpointer``) — the trainer never blocks on disk;
+  happens asynchronously per paper §2.7), copied to the host on the
+  trainer thread, and written by a background thread
+  (``AsyncCheckpointer``); ``run`` waits for the last one to reach disk and
+  raises if any save failed;
 * crash/restart resumes from the newest atomic checkpoint + the stateless
   data pipeline cursor;
 * stragglers are detected by a step-time EWMA z-test; mitigation is a
@@ -24,9 +26,12 @@ from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint.store import AsyncCheckpointer, CheckpointStore
 from repro.data.pipeline import DataConfig, Pipeline, make_batch
+from repro.launch.mesh import dp_axes
 from repro.models.backbone import Backbone
 from repro.optim import adamw
 from repro.runtime.steps import (StepSettings, init_train_state,
@@ -99,8 +104,13 @@ class Trainer:
 
         step_fn = make_train_step(bb, opt_cfg, settings)
         if mesh is not None and state_shardings is not None:
-            self._step = jax.jit(step_fn, in_shardings=(state_shardings, None),
-                                 donate_argnums=(0,))
+            # the batch splits over the data axes; state keeps its layout
+            # from step to step, so each output can be donated back in
+            batch_sh = NamedSharding(mesh, P(dp_axes(mesh) or None))
+            self._step = jax.jit(
+                step_fn, in_shardings=(state_shardings, batch_sh),
+                out_shardings=(state_shardings, NamedSharding(mesh, P())),
+                donate_argnums=(0,))
         else:
             self._step = jax.jit(step_fn, donate_argnums=(0,))
 
@@ -123,8 +133,12 @@ class Trainer:
             self.start_step = step
             print(f"[trainer] resumed from checkpoint step {step}")
         else:
-            state = init_train_state(self.bb, jax.random.PRNGKey(seed),
-                                     self.settings)
+            # jitted so a sharded state is born sharded: it may not fit on
+            # one device whole
+            init = lambda k: init_train_state(self.bb, k, self.settings)
+            init = (jax.jit(init) if self.state_shardings is None else
+                    jax.jit(init, out_shardings=self.state_shardings))
+            state = init(jax.random.PRNGKey(seed))
             self.start_step = 0
         self.store.commit_step(None, None, self.start_step)  # cursor only
         return state

@@ -11,7 +11,7 @@ import sys
 
 import pytest
 
-from repro.launch.roofline import PEAK_FLOPS, RooflineTerms
+from repro.launch.roofline import RooflineTerms, chip_peaks
 from repro.models import PartitionPlan, get_config
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -49,11 +49,17 @@ def test_step_suprema_exact_counts():
 
 
 def test_roofline_terms_dominant_and_fraction():
+    peaks = chip_peaks("TPU v5 lite")
     t = RooflineTerms(compute_s=0.5, memory_s=0.2, collective_s=0.8,
-                      model_flops=PEAK_FLOPS * 0.4 * 256, hlo_flops=1e14,
-                      useful_ratio=0.5, n_chips=256)
+                      model_flops=peaks.flops * 0.4 * 256, hlo_flops=1e14,
+                      useful_ratio=0.5, peaks=peaks, n_chips=256)
     assert t.dominant == "collective"
     assert t.roofline_fraction == pytest.approx(0.4 / 0.8)
+
+
+def test_roofline_peaks_refuse_unknown_device_kind():
+    with pytest.raises(ValueError, match="no published peaks"):
+        chip_peaks("cpu")
 
 
 # --------------------------------------------------------------------------- #

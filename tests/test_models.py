@@ -68,6 +68,23 @@ def test_arch_decode_matches_prefill(arch):
     assert int(cache2["pos"]) == S + 1
 
 
+@pytest.mark.parametrize("S", [5, 8, 13, 19])
+def test_ring_fill_matches_slot_scatter(S):
+    """Prefill's ring-buffer cache equals scattering the last min(C, S)
+    positions into slot ``p % C``, for prompts shorter than, equal to and
+    longer than the ring (once and twice around)."""
+    from repro.models.backbone import _ring_fill
+    C = 8
+    vals = jnp.arange(2 * S * 3, dtype=jnp.float32).reshape(2, S, 3) + 1
+    n = min(C, S)
+    sel = jnp.arange(S - n, S)
+    want = jnp.zeros((2, C, 3)).at[:, sel % C].set(vals[:, S - n:])
+    np.testing.assert_array_equal(_ring_fill(vals, C, 1, 0), want)
+    pos = jnp.arange(S, dtype=jnp.int32)
+    np.testing.assert_array_equal(_ring_fill(pos, C, 0, -1),
+                                  jnp.full((C,), -1).at[sel % C].set(sel))
+
+
 @pytest.mark.parametrize("arch", ["qwen2-7b", "gemma2-2b", "rwkv6-3b"])
 def test_tp_padding_is_exact(arch):
     """Zero-padded heads / replicated KV (PartitionPlan) must not change the

@@ -8,7 +8,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.checkpoint.store import AsyncCheckpointer, CheckpointStore
+from repro.checkpoint.store import (AsyncCheckpointer, CheckpointError,
+                                    CheckpointStore)
 from repro.data.pipeline import DataConfig, Pipeline, make_batch
 from repro.models import Backbone, LayerGroup, ModelConfig
 from repro.optim import adamw
@@ -82,6 +83,25 @@ def test_async_checkpointer_writes_and_reports(tmp_path):
     ac.stop()
     assert ac.saved == [10] and done == [10] and ac.errors == []
     assert store.latest_step() == 10
+
+
+def test_async_checkpointer_raises_on_failed_save(tmp_path):
+    """A save that fails must surface from drain/stop, not end the run
+    looking healthy."""
+    store = CheckpointStore(str(tmp_path))
+
+    def broken_save(tree, step):
+        raise OSError("disk full")
+
+    store.save = broken_save
+    ac = AsyncCheckpointer(store)
+    ac.submit({"a": jnp.ones((3,))}, 10)
+    with pytest.raises(CheckpointError, match="disk full"):
+        ac.drain()
+    with pytest.raises(CheckpointError):
+        ac.stop()
+    assert not ac._thread.is_alive()
+    assert store.latest_step() is None
 
 
 # --------------------------------------------------------------------------- #
