@@ -3,11 +3,15 @@
 Model (DESIGN.md §9):
 
 * A :class:`Tracer` is one *site* — one track in the merged trace: a
-  node (``node:<name>``) or a client (``client:<id>``). Each site has
-  its own clock callable, which is how the two clock domains coexist:
-  TCP/in-process sites read ``time.monotonic``; simnet sites read the
-  virtual clock, so a simulated run's trace is a pure function of the
-  seed and replays byte-identically.
+  node (``node:<name>``), a client (``client:<id>``) or the training
+  loop (``trainer``: ``Trainer.run``'s step and phase spans, which carry
+  the step in ``pv``, and the transaction events of the trainer's store).
+  Each site has its own clock callable, which is how the two clock
+  domains coexist: TCP/in-process sites and the trainer read
+  ``time.monotonic``; simnet sites read the virtual clock, so a simulated
+  run's trace is a pure function of the seed and replays
+  byte-identically. ``repro.runtime.profiling`` maps the trainer's clock
+  onto a JAX profile's through one anchor annotation.
 * Within a tracer, each *thread* owns a private ring buffer and appends
   40-byte packed event records to it without taking any lock (the only
   lock is one-time ring registration). Rings overwrite oldest-first

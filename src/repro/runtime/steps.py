@@ -38,7 +38,28 @@ def make_train_step(bb: Backbone, opt_cfg: adamw.AdamWConfig,
                     ) -> Callable:
     """(state, batch) -> (state, metrics); state = {params, opt, error?}."""
 
-    def train_step(state: Dict[str, Any], batch: Dict[str, jax.Array]):
+    def scoped_train_step(state: Dict[str, Any],
+                          batch: Dict[str, jax.Array]):
+        # the two scopes name the step's phases in a device trace: every
+        # op of the forward and backward carries "loss" in its op name,
+        # every op of the update "optimizer". The persistent compile cache
+        # keys a program by its name and its HLO without op names, so a
+        # step of the same name built before the scopes would be loaded in
+        # its place and carry none: the step is named apart from it.
+        with jax.named_scope("loss"):
+            loss, grads = loss_and_grads(state["params"], batch)
+        if settings.compress_grads:
+            grads, err = adamw.compress_with_feedback(grads, state["error"])
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, metrics = adamw.apply_updates(
+                opt_cfg, state["params"], state["opt"], grads)
+        new_state = {"params": new_params, "opt": new_opt}
+        if settings.compress_grads:
+            new_state["error"] = err
+        metrics = dict(metrics, loss=loss)
+        return new_state, metrics
+
+    def loss_and_grads(params, batch):
         k = settings.microbatches
         if k > 1:
             # gradient accumulation: scan over k microbatch slices; the
@@ -52,33 +73,20 @@ def make_train_step(bb: Backbone, opt_cfg: adamw.AdamWConfig,
                 acc, loss_acc = carry
                 mb = jax.tree_util.tree_map(lambda a: slice_mb(i, a), batch)
                 l, g = jax.value_and_grad(lambda p: bb.loss_fn(p, mb))(
-                    state["params"])
+                    params)
                 acc = jax.tree_util.tree_map(jnp.add, acc, g)
                 return (acc, loss_acc + l), None
 
             zeros = jax.tree_util.tree_map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), state["params"])
+                lambda p: jnp.zeros(p.shape, jnp.float32), params)
             (grads, loss), _ = jax.lax.scan(
                 mb_body, (zeros, jnp.zeros((), jnp.float32)),
                 jnp.arange(k))
             grads = jax.tree_util.tree_map(lambda g: g / k, grads)
-            loss = loss / k
-        else:
-            def loss_of(p):
-                return bb.loss_fn(p, batch)
+            return loss / k, grads
+        return jax.value_and_grad(lambda p: bb.loss_fn(p, batch))(params)
 
-            loss, grads = jax.value_and_grad(loss_of)(state["params"])
-        if settings.compress_grads:
-            grads, err = adamw.compress_with_feedback(grads, state["error"])
-        new_params, new_opt, metrics = adamw.apply_updates(
-            opt_cfg, state["params"], state["opt"], grads)
-        new_state = {"params": new_params, "opt": new_opt}
-        if settings.compress_grads:
-            new_state["error"] = err
-        metrics = dict(metrics, loss=loss)
-        return new_state, metrics
-
-    return train_step
+    return scoped_train_step
 
 
 def init_train_state(bb: Backbone, key: jax.Array,

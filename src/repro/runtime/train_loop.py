@@ -33,7 +33,10 @@ from repro.checkpoint.store import AsyncCheckpointer, CheckpointStore
 from repro.data.pipeline import DataConfig, Pipeline, make_batch
 from repro.launch.mesh import dp_axes
 from repro.models.backbone import Backbone
+from repro.obs import txtrace as _txtrace
 from repro.optim import adamw
+from repro.runtime import profiling
+from repro.runtime.profiling import OFF
 from repro.runtime.steps import (StepSettings, init_train_state,
                                  make_train_step)
 from repro.txstore.store import VersionedStateStore
@@ -96,6 +99,7 @@ class Trainer:
         self.straggler_hook = straggler_hook
 
         self.store = VersionedStateStore()
+        profiling.watch_compiles()
         self.ckpt = CheckpointStore(tcfg.ckpt_dir)
         self.async_ckpt = AsyncCheckpointer(
             self.ckpt, on_done=self._on_ckpt_done)
@@ -146,38 +150,84 @@ class Trainer:
     # ------------------------------------------------------------------ #
     def run(self, state: Dict[str, Any], *, crash_at: Optional[int] = None
             ) -> Dict[str, Any]:
+        """Train from ``start_step`` to ``tcfg.total_steps``.
+
+        While ``txtrace.enabled`` is on (``run`` turns it on for its own
+        length when it starts under a JAX profile, and then writes the
+        profiling anchor first), each step is one
+        ``StepTraceAnnotation("train", step_num=step)`` holding one span
+        per phase: ``train.batch`` (``next(pipe)``), ``train.dispatch``
+        (the step call: host-to-device copy and enqueue),
+        ``train.loss_sync`` (``float(loss)``), ``train.commit``
+        (``store.commit_step``) and, on save steps, ``train.ckpt``. Each is
+        a profiler annotation and a txtrace span on the ``trainer`` site
+        (``repro.runtime.profiling``), on the site's monotonic clock, on
+        this thread only. The store's transaction events (``txn``,
+        ``commit``, ``vwait``, ``lw_apply``, ...) land on the same site,
+        inside ``train.commit``, and lengthen it by their writing. With
+        tracing off each site costs one attribute read."""
         pipe = Pipeline(self.data_cfg, start_step=self.start_step)
-        for step in range(self.start_step, self.tcfg.total_steps):
+        # the store's cells stamp their own events with the site; the
+        # transaction's client-side spans (``txn``, ``commit``) go to this
+        # thread's tracer, so it is bound to the site for run's length
+        bound = _txtrace.thread_tracer()
+        _txtrace.set_thread_tracer(profiling.TRAINER)
+        profiled = profiling.profiled()
+        switched = profiled and not _txtrace.enabled
+        if switched:
+            _txtrace.enable()
+        if profiled:
+            profiling.anchor()
+        try:
+            for step in range(self.start_step, self.tcfg.total_steps):
+                on = _txtrace.enabled
+                with profiling.step_span(step) if on else OFF:
+                    state = self._step_once(state, step, pipe, on, crash_at)
+        finally:
+            if switched:
+                _txtrace.disable()
+            _txtrace.set_thread_tracer(bound)
+        self.async_ckpt.drain()
+        return state
+
+    def _step_once(self, state: Dict[str, Any], step: int, pipe: Pipeline,
+                   on: bool, crash_at: Optional[int]) -> Dict[str, Any]:
+        """One step of ``run``; ``on``: tracing was on when it began."""
+        phase = profiling.phase
+        with phase("train.batch", step) if on else OFF:
             batch = next(pipe)
-            t0 = time.monotonic()
-            if crash_at is not None and step == crash_at:
-                raise RuntimeError(f"injected crash at step {step}")
+        t0 = time.monotonic()
+        if crash_at is not None and step == crash_at:
+            raise RuntimeError(f"injected crash at step {step}")
+        with phase("train.dispatch", step) if on else OFF:
             state, metrics = self._step(state, batch)
+        with phase("train.loss_sync", step) if on else OFF:
             loss = float(metrics["loss"])
-            dt = time.monotonic() - t0
-            if self.straggler.observe(step=step, dt=dt,
-                                      z_thresh=self.tcfg.straggler_zscore,
-                                      warmup=self.tcfg.straggler_warmup):
-                ev = self.straggler.events[-1]
-                print(f"[straggler] step {step}: {dt*1e3:.1f}ms "
-                      f"(z={ev['z']:.1f}) — mitigation hook invoked")
-                if self.straggler_hook:
-                    self.straggler_hook(ev)
-            self.metrics_log.append({"step": step, "loss": loss, "dt": dt})
-            # control-plane commit: one write txn over (params, opt, cursor)
+        dt = time.monotonic() - t0
+        if self.straggler.observe(step=step, dt=dt,
+                                  z_thresh=self.tcfg.straggler_zscore,
+                                  warmup=self.tcfg.straggler_warmup):
+            ev = self.straggler.events[-1]
+            print(f"[straggler] step {step}: {dt*1e3:.1f}ms "
+                  f"(z={ev['z']:.1f}) — mitigation hook invoked")
+            if self.straggler_hook:
+                self.straggler_hook(ev)
+        self.metrics_log.append({"step": step, "loss": loss, "dt": dt})
+        # control-plane commit: one write txn over (params, opt, cursor)
+        with phase("train.commit", step) if on else OFF:
             self.store.commit_step(state["params"], state["opt"], step + 1)
-            if (step + 1) % self.tcfg.ckpt_every == 0:
-                # irrevocable read-only txn -> consistent async snapshot;
-                # materialize to host NOW (the copy-buffer copy): the live
-                # device buffers are donated into the next step
+        if (step + 1) % self.tcfg.ckpt_every == 0:
+            # irrevocable read-only txn -> consistent async snapshot;
+            # materialize to host NOW (the copy-buffer copy): the live
+            # device buffers are donated into the next step
+            with phase("train.ckpt", step) if on else OFF:
                 snap = self.store.snapshot(("params", "opt", "data_cursor"))
                 host = jax.device_get({"params": snap["params"],
                                        "opt": snap["opt"]})
                 self.async_ckpt.submit(host, snap["data_cursor"])
-            if (step + 1) % self.tcfg.log_every == 0:
-                print(f"[train] step {step+1}: loss={loss:.4f} "
-                      f"({dt*1e3:.0f}ms/step)")
-        self.async_ckpt.drain()
+        if (step + 1) % self.tcfg.log_every == 0:
+            print(f"[train] step {step+1}: loss={loss:.4f} "
+                  f"({dt*1e3:.0f}ms/step)")
         return state
 
     def shutdown(self) -> None:

@@ -29,6 +29,7 @@ from typing import Any, Callable, Dict, Iterable, List, Optional
 
 from repro.core import (Mode, Registry, SharedObject, Transaction,
                         TransactionMonitor, access)
+from repro.obs import txtrace as _txtrace
 
 
 class StateCell:
@@ -77,6 +78,10 @@ class VersionedStateStore:
     """Named state cells + transaction factories for the runtime actors."""
 
     CELLS = ("params", "opt", "data_cursor", "ckpt_meta")
+    #: the txtrace site of the cells' transaction events (``vwait``,
+    #: ``lw_apply``, ...), as a node server stamps its own objects; the
+    #: trainer's spans land there too (``repro.runtime.profiling``)
+    SITE = "trainer"
 
     def __init__(self, *, monitor_timeout: float = 30.0):
         self.registry = Registry()
@@ -85,6 +90,7 @@ class VersionedStateStore:
         for name in self.CELLS:
             self.cells[name] = self.registry.bind(
                 name, StateCell(), node=self.node)
+            self.cells[name].header.obs_tracer = _txtrace.tracer(self.SITE)
         self.monitor = TransactionMonitor(self.registry,
                                           timeout=monitor_timeout)
         self.monitor.start()

@@ -273,3 +273,88 @@ def test_stats_rpc_carries_metrics_snapshot():
     m = out["stats"]["metrics"]
     assert m["site"].startswith("node:")
     assert "counters" in m and "histograms" in m
+
+
+# --------------------------------------------------------------------------- #
+# compile log                                                                  #
+# --------------------------------------------------------------------------- #
+def test_compile_log_counts_a_new_shape_once_and_a_repeat_never():
+    import jax
+    import jax.numpy as jnp
+
+    from repro.obs import compiles
+    from repro.runtime import profiling
+
+    def double_plus_one(x):
+        return x * 2 + 1
+
+    f = jax.jit(double_plus_one)
+    built = lambda: [r for r in compiles.LOG.records
+                     if r[0] == "jit(double_plus_one)" and r[1] == "compile"]
+    stages = lambda: [r[1] for r in compiles.LOG.records
+                      if "double_plus_one" in r[0]]
+    profiling.watch_compiles()
+    compiles.LOG.reset()
+    try:
+        txtrace.enable()
+        f(jnp.ones(3)).block_until_ready()
+        assert [r[1] for r in built()] == ["compile"]
+        assert stages() == ["trace", "lower", "compile"]
+        f(jnp.ones(3) * 5).block_until_ready()
+        assert len(built()) == 1 and len(stages()) == 3
+        f(jnp.ones(4)).block_until_ready()
+        assert [r[1] for r in built()] == ["compile", "compile"]
+        assert all(r[2] > 0 and r[3] is None for r in built())
+        txtrace.disable()
+        f(jnp.ones(5)).block_until_ready()     # kept with tracing off too
+        assert len(built()) == 3
+    finally:
+        compiles.LOG.reset()
+
+
+def test_compile_log_tells_a_cache_load_from_a_compile():
+    from repro.obs import compiles
+    from repro.runtime import profiling
+
+    compiles.LOG.reset()
+    try:
+        profiling._on_event(profiling.CACHE_HIT_EVENT)
+        profiling._on_duration(profiling.COMPILE_EVENT, 0.5, fun_name="jit(g)")
+        profiling._on_duration(profiling.COMPILE_EVENT, 2.0, fun_name="jit(h)")
+        profiling._on_duration("/jax/other", 9.0, fun_name="jit(k)")
+        assert [r[:4] for r in compiles.LOG.records] == [
+            ("jit(g)", "load", 0.5, None), ("jit(h)", "compile", 2.0, None)]
+        t = compiles.LOG.records[0][4]
+        # the two intervals overlap (recorded a moment apart): the union
+        compiled, loaded, seconds = compiles.LOG.between(t, float("inf"))
+        assert (compiled, loaded) == (1, 1)
+        assert seconds == pytest.approx(2.0, abs=0.01)
+        assert compiles.LOG.between(float("-inf"), t) == (0, 0, 0.0)
+    finally:
+        compiles.LOG.reset()
+
+
+def test_compile_log_counts_nested_stages_once():
+    """A nested function's trace lies inside its caller's: the seconds are
+    the union of the records' intervals, and only compiles and loads are
+    counted as programs."""
+    from repro.obs import compiles
+    from repro.runtime import profiling
+
+    trace, lower = sorted(profiling.STAGE_EVENTS,
+                          key=profiling.STAGE_EVENTS.get, reverse=True)
+    compiles.LOG.reset()
+    try:
+        profiling._on_duration(trace, 0.1, fun_name="inner")
+        profiling._on_duration(lower, 0.2, fun_name="jit(outer)")
+        assert [r[:2] for r in compiles.LOG.records] == [
+            ("inner", "trace"), ("jit(outer)", "lower")]
+        log = compiles.CompileLog()
+        log.records = [("inner", "trace", 1.0, None, 11.0),
+                       ("outer", "trace", 3.0, None, 12.0),
+                       ("jit(outer)", "lower", 2.0, None, 14.0),
+                       ("jit(outer)", "compile", 5.0, None, 20.0)]
+        assert log.between(0.0, 30.0) == (1, 0, 10.0)
+        assert log.between(0.0, 13.0) == (0, 0, 3.0)
+    finally:
+        compiles.LOG.reset()
