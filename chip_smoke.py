@@ -62,8 +62,9 @@ from repro.runtime.train_loop import Trainer, TrainerConfig  # noqa: E402
 
 ARCH = "qwen3-4b"
 GiB = 2 ** 30
-# Deepest cut whose train step fits one 16 GiB v5e: 13.97 GiB compiled peak
-# at batch 4 x seq 512 (tests/test_tpu_compile.py keeps it under 16 GiB).
+# Deepest cut whose train step fits one 16 GiB v5e: 14.52 GiB compiled peak
+# at batch 4 x seq 512 with the layers' projections saved for the backward,
+# 13.97 with full remat (tests/test_tpu_compile.py keeps it under 16 GiB).
 TRAIN_LAYERS = 5
 TRAIN_BATCH, TRAIN_SEQ, TRAIN_STEPS = 4, 512, 8
 SERVE_SLOTS, SERVE_REQUESTS, PROMPT_LEN, MAX_NEW = 4, 8, 64, 16

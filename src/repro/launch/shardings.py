@@ -230,7 +230,6 @@ def sharded_backbone(cfg: ModelConfig, mesh: Mesh, global_batch: int,
                   compute_dtype=jnp.bfloat16,
                   param_dtype=jnp.bfloat16 if serve else jnp.float32,
                   remat=settings.remat and not serve,
-                  remat_policy=settings.remat_policy,
                   sharder=make_sharder(cfg, mesh,
                                        batch_sharded=global_batch > 1,
                                        global_batch=global_batch),

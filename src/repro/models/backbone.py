@@ -39,6 +39,17 @@ Params = Dict[str, Any]
 AUX_COEF = 0.01
 _RWKV_LORA = 64
 
+#: What a checkpointed layer keeps for the backward, by name. "dots": the
+#: outputs of its matmuls with no batch dims (its projections) that the
+#: backward reads, so it recomputes none of them; "full": only the layer's
+#: input, so the backward recomputes the whole layer. The trainer tries them
+#: in this order and keeps the first whose compiled step fits the device
+#: (``repro.runtime.steps.compile_train_step``).
+REMAT_POLICIES = {
+    "dots": jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+    "full": None,
+}
+
 
 def _no_shard(x: jax.Array, name: str) -> jax.Array:
     return x
@@ -65,7 +76,6 @@ class Backbone:
                  sharder: Callable[[jax.Array, str], jax.Array] = _no_shard,
                  param_gather: Optional[Callable[[Params], Params]] = None,
                  moe_impl: str = "gspmd",
-                 remat_policy: str = "full",
                  mesh=None, dp_axes: Tuple[str, ...] = ()):
         plan.check(cfg)
         self.cfg = cfg
@@ -73,7 +83,6 @@ class Backbone:
         self.compute_dtype = compute_dtype
         self.param_dtype = param_dtype
         self.remat = remat
-        self.remat_policy = remat_policy
         self.shard = sharder
         self.param_gather = param_gather
         self.moe_impl = moe_impl
@@ -388,17 +397,14 @@ class Backbone:
             out = self.param_gather(out)
         return out
 
-
-    def _checkpoint(self, fn):
-        """Wrap a scan body in jax.checkpoint per the configured policy."""
+    def _checkpoint(self, fn, policy: str = "full"):
+        """Wrap a scan body in jax.checkpoint under ``policy``, a name in
+        :data:`REMAT_POLICIES`."""
         if not self.remat:
             return fn
-        if self.remat_policy == "dots":
-            pol = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-            return jax.checkpoint(fn, policy=pol)
-        return jax.checkpoint(fn)
+        return jax.checkpoint(fn, policy=REMAT_POLICIES[policy])
 
-    def _run_groups(self, params, x, positions, enc_kv=None):
+    def _run_groups(self, params, x, positions, remat_policy, enc_kv=None):
         """Scan every segment; returns (x, total_aux)."""
         total_aux = jnp.zeros((), jnp.float32)
         for gi, group in enumerate(self.cfg.groups):
@@ -413,12 +419,12 @@ class Backbone:
                     aux = aux + a
                 return (h, aux), None
 
-            scan_body = self._checkpoint(body)
+            scan_body = self._checkpoint(body, remat_policy)
             (x, total_aux), _ = jax.lax.scan(
                 scan_body, (x, total_aux), gp)
         return x, total_aux
 
-    def _encode(self, params, frames) -> jax.Array:
+    def _encode(self, params, frames, remat_policy) -> jax.Array:
         """Whisper encoder over precomputed (stub-frontend) frames."""
         cfg = self.cfg
         x = frames.astype(self.compute_dtype)
@@ -438,7 +444,7 @@ class Backbone:
                     aux = aux + a
                 return (h, aux), None
 
-            scan_body = self._checkpoint(body)
+            scan_body = self._checkpoint(body, remat_policy)
             (x, total_aux), _ = jax.lax.scan(scan_body, (x, total_aux), gp)
         return x
 
@@ -446,7 +452,8 @@ class Backbone:
         return [(gi, g) for gi, g in enumerate(self.cfg.groups)
                 if "enc" not in g.pattern]
 
-    def _run_decoder(self, params, x, positions, enc_out=None):
+    def _run_decoder(self, params, x, positions, remat_policy,
+                     enc_out=None):
         total_aux = jnp.zeros((), jnp.float32)
         enc_kv = None
         if enc_out is not None:
@@ -471,18 +478,21 @@ class Backbone:
                     aux = aux + a
                 return (h, aux), None
 
-            scan_body = self._checkpoint(body)
+            scan_body = self._checkpoint(body, remat_policy)
             (x, total_aux), _ = jax.lax.scan(scan_body, (x, total_aux), gp)
         return x, total_aux
 
-    def loss_fn(self, params: Params, batch: Dict[str, jax.Array]) -> jax.Array:
-        logits, aux = self.forward(params, batch)
+    def loss_fn(self, params: Params, batch: Dict[str, jax.Array],
+                remat_policy: str = "full") -> jax.Array:
+        """The loss; with ``remat``, each layer is checkpointed under
+        ``remat_policy``, a name in :data:`REMAT_POLICIES`."""
+        logits, aux = self.forward(params, batch, remat_policy)
         loss = stable_cross_entropy(logits, batch["labels"],
                                     self.cfg.final_logit_softcap)
         return loss + AUX_COEF * aux
 
-    def forward(self, params: Params, batch: Dict[str, jax.Array]
-                ) -> Tuple[jax.Array, jax.Array]:
+    def forward(self, params: Params, batch: Dict[str, jax.Array],
+                remat_policy: str = "full") -> Tuple[jax.Array, jax.Array]:
         """Logits at every position [B, S, Vp] and the MoE aux loss."""
         cfg = self.cfg
         tokens = batch["tokens"]
@@ -490,10 +500,11 @@ class Backbone:
         x = self.shard(x, "act_hidden")
         positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
         if cfg.is_enc_dec:
-            enc_out = self._encode(params, batch["enc_frames"])
-            x, aux = self._run_decoder(params, x, positions, enc_out)
+            enc_out = self._encode(params, batch["enc_frames"], remat_policy)
+            x, aux = self._run_decoder(params, x, positions, remat_policy,
+                                       enc_out)
         else:
-            x, aux = self._run_groups(params, x, positions)
+            x, aux = self._run_groups(params, x, positions, remat_policy)
         return self._logits(params, x), aux
 
     # ------------------------------------------------------------------ #
@@ -642,7 +653,7 @@ class Backbone:
         positions = jnp.arange(S, dtype=jnp.int32)
         enc_out = None
         if cfg.is_enc_dec:
-            enc_out = self._encode(params, batch["enc_frames"])
+            enc_out = self._encode(params, batch["enc_frames"], "full")
         new_cache: Params = {"pos": jnp.asarray(S, jnp.int32)}
         for gi, group in self._decoder_groups():
             gp = params[f"g{gi}"]

@@ -6,14 +6,13 @@ the end-to-end example.
 """
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.models.backbone import Backbone
+from repro.models.backbone import REMAT_POLICIES, Backbone
 from repro.optim import adamw
 
 Params = Any
@@ -26,7 +25,9 @@ class StepSettings:
     zero3: bool = True          # ZeRO-3 "data"-sharded parameters
     gather_weights: bool = True  # per-layer weight all-gather in the scan body
     remat: bool = True
-    remat_policy: str = "full"  # full | dots (save matmul outputs)
+    # a name in models.backbone.REMAT_POLICIES, or None: the trainer takes
+    # the first of them whose compiled step fits (compile_train_step)
+    remat_policy: Optional[str] = None
     compress_grads: bool = False
     moe_ep: bool = True         # expert-parallel MoE via shard_map (§Perf)
     microbatches: int = 1       # gradient accumulation: divides the saved-
@@ -36,7 +37,13 @@ class StepSettings:
 def make_train_step(bb: Backbone, opt_cfg: adamw.AdamWConfig,
                     settings: StepSettings = StepSettings()
                     ) -> Callable:
-    """(state, batch) -> (state, metrics); state = {params, opt, error?}."""
+    """(state, batch) -> (state, metrics); state = {params, opt, error?}.
+    The layers are checkpointed under ``settings.remat_policy``, or fully
+    where it names none."""
+    policy = settings.remat_policy or "full"
+    if policy not in REMAT_POLICIES:
+        raise ValueError(f"remat policy {policy!r}: one of "
+                         f"{list(REMAT_POLICIES)}")
 
     def scoped_train_step(state: Dict[str, Any],
                           batch: Dict[str, jax.Array]):
@@ -72,8 +79,8 @@ def make_train_step(bb: Backbone, opt_cfg: adamw.AdamWConfig,
             def mb_body(carry, i):
                 acc, loss_acc = carry
                 mb = jax.tree_util.tree_map(lambda a: slice_mb(i, a), batch)
-                l, g = jax.value_and_grad(lambda p: bb.loss_fn(p, mb))(
-                    params)
+                l, g = jax.value_and_grad(
+                    lambda p: bb.loss_fn(p, mb, policy))(params)
                 acc = jax.tree_util.tree_map(jnp.add, acc, g)
                 return (acc, loss_acc + l), None
 
@@ -84,9 +91,53 @@ def make_train_step(bb: Backbone, opt_cfg: adamw.AdamWConfig,
                 jnp.arange(k))
             grads = jax.tree_util.tree_map(lambda g: g / k, grads)
             return loss / k, grads
-        return jax.value_and_grad(lambda p: bb.loss_fn(p, batch))(params)
+        return jax.value_and_grad(
+            lambda p: bb.loss_fn(p, batch, policy))(params)
 
     return scoped_train_step
+
+
+def memory_limit(args) -> Optional[int]:
+    """Bytes a program run on ``args`` may take on each device ``args``
+    live on, at most: the device's ``bytes_limit``, less what it holds
+    besides ``args`` (``bytes_in_use`` counts them). The least over the
+    devices; None where a device has no limit to read (the CPU)."""
+    held: Dict[Any, int] = {}
+    for leaf in jax.tree_util.tree_leaves(args):
+        for shard in getattr(leaf, "addressable_shards", ()):
+            held[shard.device] = held.get(shard.device, 0) \
+                + shard.data.nbytes
+    room = []
+    for device, own in held.items():
+        stats = device.memory_stats() or {}
+        if "bytes_limit" not in stats:
+            return None
+        room.append(stats["bytes_limit"] - stats.get("bytes_in_use", 0)
+                    + own)
+    return min(room, default=None)
+
+
+def compile_train_step(build: Callable[[Optional[str]], Any],
+                       policies: Sequence[Optional[str]],
+                       args: Tuple[Any, ...], limit: Optional[int]
+                       ) -> Tuple[Any, Optional[str]]:
+    """Lower and compile ``build(policy)`` (a jitted train step) at
+    ``args`` under each of ``policies`` in turn, and keep the first the
+    device can hold: one the compiler did not refuse for memory, whose
+    peak is at most ``limit`` bytes (any peak where ``limit`` is None). The
+    last is kept whatever its peak. Returns (the compiled step, its
+    policy)."""
+    for i, policy in enumerate(policies):
+        last = i == len(policies) - 1
+        try:
+            compiled = build(policy).lower(*args).compile()
+        except jax.errors.JaxRuntimeError as e:
+            if last or "RESOURCE_EXHAUSTED" not in str(e):
+                raise
+            continue
+        peak = compiled.memory_analysis().peak_memory_in_bytes
+        if last or limit is None or peak <= limit:
+            return compiled, policy
 
 
 def init_train_state(bb: Backbone, key: jax.Array,

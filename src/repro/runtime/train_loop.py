@@ -20,6 +20,7 @@ The control plane runs on the transactional store (``repro.txstore``):
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
@@ -32,13 +33,15 @@ from jax.sharding import PartitionSpec as P
 from repro.checkpoint.store import AsyncCheckpointer, CheckpointStore
 from repro.data.pipeline import DataConfig, Pipeline, make_batch
 from repro.launch.mesh import dp_axes
-from repro.models.backbone import Backbone
+from repro.models.backbone import REMAT_POLICIES, Backbone
+from repro.obs import metrics as _metrics
 from repro.obs import txtrace as _txtrace
 from repro.optim import adamw
 from repro.runtime import profiling
 from repro.runtime.profiling import OFF
-from repro.runtime.steps import (StepSettings, init_train_state,
-                                 make_train_step)
+from repro.runtime.steps import (StepSettings, compile_train_step,
+                                 init_train_state, make_train_step,
+                                 memory_limit)
 from repro.txstore.store import VersionedStateStore
 
 
@@ -106,17 +109,51 @@ class Trainer:
         self.straggler = StragglerStats()
         self.metrics_log: List[Dict[str, float]] = []
 
-        step_fn = make_train_step(bb, opt_cfg, settings)
         if mesh is not None and state_shardings is not None:
             # the batch splits over the data axes; state keeps its layout
             # from step to step, so each output can be donated back in
             batch_sh = NamedSharding(mesh, P(dp_axes(mesh) or None))
-            self._step = jax.jit(
-                step_fn, in_shardings=(state_shardings, batch_sh),
+            self._jit_kw = dict(
+                in_shardings=(state_shardings, batch_sh),
                 out_shardings=(state_shardings, NamedSharding(mesh, P())),
                 donate_argnums=(0,))
         else:
-            self._step = jax.jit(step_fn, donate_argnums=(0,))
+            self._jit_kw = dict(donate_argnums=(0,))
+        self._compiled = None
+        #: the checkpoint policy the train step compiled with (None: its
+        #: layers are not checkpointed, or it has not compiled yet)
+        self.remat_policy: Optional[str] = None
+
+    def _step(self, state: Dict[str, Any], batch: Dict[str, Any]):
+        """One train step; the first call compiles it."""
+        if self._compiled is None:
+            self._compiled = self._compile_step(state, batch)
+        return self._compiled(state, batch)
+
+    def _compile_step(self, state, batch):
+        """The train step compiled under the first checkpoint policy whose
+        program fits the devices the step runs on (``compile_train_step``;
+        the one ``settings`` names, where it names one), recorded on the
+        ``trainer`` site's metrics: a count of compiles per policy, and the
+        compiled step's temporary bytes, the saved residuals among them."""
+        def build(policy):
+            settings = dataclasses.replace(self.settings, remat_policy=policy)
+            return jax.jit(make_train_step(self.bb, self.opt_cfg, settings),
+                           **self._jit_kw)
+
+        policies = ((None,) if not self.bb.remat
+                    else (self.settings.remat_policy,)
+                    if self.settings.remat_policy else tuple(REMAT_POLICIES))
+        compiled, self.remat_policy = compile_train_step(
+            build, policies, (state, batch), memory_limit((state, batch)))
+        mem = compiled.memory_analysis()
+        reg = _metrics.registry(profiling.TRAINER.site)
+        reg.counter(f"train_step.remat.{self.remat_policy or 'none'}").inc()
+        reg.counter("train_step.temp_bytes").inc(mem.temp_size_in_bytes)
+        print(f"[trainer] train step compiled with remat policy "
+              f"{self.remat_policy}: temp {mem.temp_size_in_bytes} bytes, "
+              f"peak {mem.peak_memory_in_bytes} bytes", flush=True)
+        return compiled
 
     # ------------------------------------------------------------------ #
     def _on_ckpt_done(self, step: int, path: str) -> None:

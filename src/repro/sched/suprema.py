@@ -6,7 +6,8 @@ analysis). For a training step this knowledge is exact and derivable from
 the model structure — this module is the "static analyzer" for our domain:
 
 * each layer-block's weights are **read** once in forward, once in backward,
-  and once more when rematerialized;
+  and once more where the backward recomputes the layer (full remat; under
+  the saving policy it recomputes none of the layer's matmuls);
 * each block's gradient is **written** once, at a known point in backward
   (→ release the gradient object immediately after: the per-layer
   reduce-scatter schedule);
@@ -21,7 +22,7 @@ release on last write).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict
+from typing import Dict, Union
 
 from repro.core.api import Suprema
 from repro.models.config import ModelConfig
@@ -40,10 +41,14 @@ class StepAccessPlan:
                        updates=self.optimizer_updates)
 
 
-def step_suprema(cfg: ModelConfig, *, remat: bool = True
+def step_suprema(cfg: ModelConfig, *,
+                 remat: Union[bool, str] = True
                  ) -> Dict[str, StepAccessPlan]:
-    """Exact access bounds per group for one train step."""
-    reads = 3 if remat else 2  # fwd, (remat-fwd), bwd
+    """Exact access bounds per group for one train step. ``remat`` is how
+    the step checkpoints its layers: a policy of
+    ``repro.models.backbone.REMAT_POLICIES``, True for a plain checkpoint
+    (``"full"``), False for none."""
+    reads = 3 if remat in (True, "full") else 2  # fwd, (remat-fwd), bwd
     plan: Dict[str, StepAccessPlan] = {}
     for gi, group in enumerate(cfg.groups):
         plan[f"g{gi}"] = StepAccessPlan(reads, 1, 1)

@@ -48,6 +48,17 @@ def test_step_suprema_exact_counts():
     assert sup.total == 5
 
 
+@pytest.mark.parametrize("remat,reads", [("dots", 2), ("full", 3),
+                                         (True, 3), (False, 2)])
+def test_step_suprema_weight_reads_follow_the_remat_policy(remat, reads):
+    """A backward that recomputes no projection reads each layer's weights
+    twice (forward, backward); full remat reads them a third time."""
+    from repro.sched import step_suprema
+    plan = step_suprema(get_config("qwen3-4b"), remat=remat)
+    assert plan["g0"].weight_reads == reads
+    assert plan["g0"].as_suprema().total == reads + 2
+
+
 def test_roofline_terms_dominant_and_fraction():
     peaks = chip_peaks("TPU v5 lite")
     t = RooflineTerms(compute_s=0.5, memory_s=0.2, collective_s=0.8,

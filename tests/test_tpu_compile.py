@@ -8,22 +8,29 @@ only one process at a time may load the TPU library.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.kernels.flash_attention import flash_attention_pallas
+from repro.launch.hlocost import HloCostModel
 from repro.kernels.rglru_kernel import rglru_scan_pallas
 from repro.kernels.rwkv6_kernel import rwkv6_scan_pallas
+from repro.launch.mesh import dp_axes, make_mesh
+from repro.launch.shardings import sharded_backbone, train_state_shardings
 from repro.models import Backbone, get_config
+from repro.models.backbone import REMAT_POLICIES
 from repro.models.config import LayerGroup
 from repro.optim import adamw
-from repro.runtime.steps import (StepSettings, make_train_step,
-                                 train_state_specs)
+from repro.runtime.steps import (StepSettings, compile_train_step,
+                                 make_train_step, train_state_specs)
 
 HBM_BYTES = 16 * 2 ** 30
+# what a v5e's runtime gives a program: "Used 15.80G of 15.75G hbm"
+V5E_BYTES_LIMIT = int(15.75 * 2 ** 30)
 
 
 @pytest.fixture(scope="module")
@@ -134,3 +141,101 @@ def test_smoke_train_step_fits_one_v5e(one_chip):
     assert mem.peak_memory_in_bytes < HBM_BYTES
     # the donated state is reused in place, so the peak is more than it
     assert mem.peak_memory_in_bytes > mem.argument_size_in_bytes
+
+
+def _activation_matmuls(hlo: HloCostModel, comp: str):
+    """Output shapes of the matmuls under ``comp`` (its fusions too)."""
+    out = []
+    for op in hlo.comps[comp].ops:
+        if op.kind in ("dot", "convolution"):
+            out.append(tuple(op.out_dims))
+        for callee in re.findall(r"(?:calls|to_apply)=%?([\w.\-]+)",
+                                 op.rest):
+            if callee in hlo.comps:
+                out += _activation_matmuls(hlo, callee)
+    return out
+
+
+def _scans(hlo: HloCostModel):
+    """(while op, its body's matmul output shapes) of the entry's loops."""
+    out = []
+    for op in hlo.comps[hlo.entry].ops:
+        if op.kind == "while":
+            body = re.search(r"body=%?([\w.\-]+)", op.rest).group(1)
+            out.append((op, _activation_matmuls(hlo, body)))
+    return out
+
+
+def test_cell_train_step_keeps_the_projections_on_one_v5e(one_chip):
+    """The benchmark cells' step (qwen3-4b, 5 layers, batch 4 x 512, fp32
+    params and AdamW state) keeps the saving policy with room under 15 GiB,
+    and its backward layer scan recomputes no projection: it takes each
+    layer's bf16 q, k, v, o, gate and up from the forward, and holds one
+    [batch, seq, ...] matmul per projection (that projection's input
+    gradient), as many as the forward scan, where full remat would hold
+    six more."""
+    B, S, layers, limit = 4, 512, 5, int(15.0 * 2 ** 30)
+    cfg = _qwen3_4b(layers)
+    settings = StepSettings()
+    bb = Backbone(cfg, remat=settings.remat)
+    state = _on_chip(one_chip, train_state_specs(bb, settings))
+    batch = {k: _spec(one_chip, (B, S), jnp.int32)
+             for k in ("tokens", "labels")}
+
+    def build(policy):
+        s = dataclasses.replace(settings, remat_policy=policy)
+        return jax.jit(make_train_step(bb, adamw.AdamWConfig(), s),
+                       donate_argnums=(0,))
+
+    compiled, policy = compile_train_step(build, tuple(REMAT_POLICIES),
+                                          (state, batch), limit)
+    assert policy == "dots"
+    assert compiled.memory_analysis().peak_memory_in_bytes <= limit
+
+    hlo = HloCostModel(compiled.as_text())
+    scans = sorted(([op, [d for d in mm if list(d[:2]) == [B, S]]]
+                    for op, mm in _scans(hlo)), key=lambda x: len(x[1]))
+    (_, forward), (loop, backward) = scans[-2:]
+    assert len(forward) == 7, forward
+    assert len(backward) == len(forward), backward
+    # a [B, S, k or v width] matmul is only ever the forward's projection
+    kv = cfg.n_kv_heads * cfg.hd
+    assert not [d for d in backward if d[2:] in ((kv,), (cfg.n_kv_heads,
+                                                          cfg.hd))]
+    # the residuals it reads instead, stacked over the layers
+    for width in (cfg.n_heads * cfg.hd, kv, cfg.d_model, cfg.d_ff):
+        assert f"bf16[{layers},{B},{S},{width}]" in loop.out_text, width
+
+
+def test_sharded_train_step_policy_on_a_v5e_2x2(topo):
+    """The four-chip smoke's sharded trainer (qwen3-4b at 8 layers, batch
+    4 x 512, ZeRO-3 over a 2x2 (data, model) mesh), jitted as ``Trainer``
+    jits it on a mesh: the saving policy fits each chip's share, so it is
+    the one kept there too."""
+    from chip_smoke import MESH_LAYERS, TRAIN_BATCH, TRAIN_SEQ
+    mesh = make_mesh((2, 2), ("data", "model"), devices=topo.devices)
+    settings = StepSettings()
+    bb, p_sh = sharded_backbone(_qwen3_4b(MESH_LAYERS), mesh, TRAIN_BATCH,
+                                settings)
+    st_sh = train_state_shardings(p_sh, mesh, settings)
+    batch_sh = NamedSharding(mesh, PartitionSpec(dp_axes(mesh) or None))
+    state = jax.tree_util.tree_map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        train_state_specs(bb, settings), st_sh)
+    batch = {k: jax.ShapeDtypeStruct((TRAIN_BATCH, TRAIN_SEQ), jnp.int32,
+                                     sharding=batch_sh)
+             for k in ("tokens", "labels")}
+
+    def build(policy):
+        s = dataclasses.replace(settings, remat_policy=policy)
+        return jax.jit(make_train_step(bb, adamw.AdamWConfig(), s),
+                       in_shardings=(st_sh, batch_sh),
+                       out_shardings=(st_sh, NamedSharding(mesh,
+                                                           PartitionSpec())),
+                       donate_argnums=(0,))
+
+    compiled, policy = compile_train_step(build, tuple(REMAT_POLICIES),
+                                          (state, batch), V5E_BYTES_LIMIT)
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    assert policy == "dots"
+    assert peak <= V5E_BYTES_LIMIT
