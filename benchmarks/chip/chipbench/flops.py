@@ -4,46 +4,38 @@ These are the yardstick: what the algorithm needs, not what the compiled
 program happens to do. Recomputation (remat's second forward) is not
 counted, and causal attention counts the half of the score matrix it uses.
 Configurations are the benchmark's JSON files, keyed as their sources are.
+Each model type's own counts are in ``archs/<model_type>.py``; the
+functions here hand a configuration to its model type's file.
 """
 from __future__ import annotations
 
 from typing import Dict, Iterable
 
+from chipbench import harness
 
-def _attn_shape(cfg: Dict):
-    return (cfg["hidden_size"], cfg["num_attention_heads"],
-            cfg["num_key_value_heads"], cfg["head_dim"],
-            cfg["intermediate_size"])
+
+def _arch(cfg: Dict):
+    return harness.arch(cfg["model_type"])
 
 
 def layer_matmul_params(cfg: Dict) -> int:
     """Weights one layer multiplies every token by (matmul operands only)."""
-    if cfg["model_type"] == "qwen3":
-        D, H, KV, hd, F = _attn_shape(cfg)
-        return D * H * hd + 2 * D * KV * hd + H * hd * D + 3 * D * F
-    if cfg["model_type"] == "rwkv6":
-        D, F = cfg["hidden_size"], cfg["intermediate_size"]
-        Dr = cfg["num_attention_heads"] * cfg["head_size"]
-        mix, dec = cfg["time_mix_extra_dim"], cfg["time_decay_extra_dim"]
-        # r, k, v, g and the output; five data-dependent lerps (each a
-        # D->mix->D low-rank pair); the decay's D->dec->Dr pair; channel mix
-        return (4 * D * Dr + Dr * D + 5 * (D * mix + mix * D)
-                + D * dec + dec * Dr + 2 * D * F + D * D)
-    raise KeyError(f"no FLOP count for model_type {cfg['model_type']!r}")
+    return _arch(cfg).layer_matmul_params(cfg)
 
 
 def matmul_params(cfg: Dict) -> int:
-    """All layers' matmul weights plus the output head (D x vocab)."""
+    """All layers' matmul weights plus the output head (D x vocab). A model
+    type whose layers differ in their weights gives its own count."""
+    own = getattr(_arch(cfg), "matmul_params", None)
+    if own is not None:
+        return own(cfg)
     return (cfg["num_hidden_layers"] * layer_matmul_params(cfg)
             + cfg["hidden_size"] * cfg["vocab_size"])
 
 
 def param_count(cfg: Dict) -> int:
-    """Every parameter of a qwen3 configuration (tied embedding once)."""
-    D, H, KV, hd, F = _attn_shape(cfg)
-    layer = layer_matmul_params(cfg) + 2 * D + 2 * hd
-    head = 1 if cfg["tie_word_embeddings"] else 2
-    return cfg["num_hidden_layers"] * layer + head * cfg["vocab_size"] * D + D
+    """Every parameter of the configuration (a tied embedding once)."""
+    return _arch(cfg).param_count(cfg)
 
 
 def causal_pairs(q_len: int, past: int = 0) -> int:
@@ -54,10 +46,7 @@ def causal_pairs(q_len: int, past: int = 0) -> int:
 
 def attn_flops(cfg: Dict, pairs: int) -> float:
     """Forward score and context products over ``pairs`` (q, k) pairs."""
-    if cfg["model_type"] != "qwen3":
-        return 0.0
-    _, H, _, hd, _ = _attn_shape(cfg)
-    return 4.0 * H * hd * pairs * cfg["num_hidden_layers"]
+    return _arch(cfg).attn_flops(cfg, pairs)
 
 
 def wkv_fwd_flops(B: int, T: int, H: int, hd: int) -> float:
@@ -77,15 +66,8 @@ def wkv_fwd_bytes(B: int, T: int, H: int, hd: int, rkv_bytes: int,
 
 def train_step_flops(cfg: Dict, batch: int, seq: int) -> float:
     """Forward and backward of one step: 6 x matmul weights x tokens, plus
-    three times the forward attention (or WKV) products."""
-    tokens = batch * seq
-    flops = 6.0 * matmul_params(cfg) * tokens
-    if cfg["model_type"] == "qwen3":
-        flops += 3.0 * attn_flops(cfg, batch * causal_pairs(seq))
-    elif cfg["model_type"] == "rwkv6":
-        flops += 3.0 * cfg["num_hidden_layers"] * wkv_fwd_flops(
-            batch, seq, cfg["num_attention_heads"], cfg["head_size"])
-    return flops
+    three times the forward products of the token mixer (attention, WKV)."""
+    return _arch(cfg).train_step_flops(cfg, batch, seq)
 
 
 def serve_flops(cfg: Dict, tokens: int, pairs: int) -> float:
@@ -95,8 +77,7 @@ def serve_flops(cfg: Dict, tokens: int, pairs: int) -> float:
 
 def decode_step_bytes(cfg: Dict, contexts: Iterable[int],
                       weight_bytes: int = 2, kv_bytes: int = 2) -> float:
-    """HBM bytes one decode step needs: every weight once, and the keys and
-    values of the live positions of the live slots only."""
-    D, H, KV, hd, F = _attn_shape(cfg)
-    per_pos = 2 * cfg["num_hidden_layers"] * KV * hd * kv_bytes
+    """HBM bytes one decode step needs: every weight once, and the cache of
+    the live positions of the live slots only."""
+    per_pos = _arch(cfg).cache_bytes_per_position(cfg, kv_bytes)
     return param_count(cfg) * weight_bytes + per_pos * sum(contexts)

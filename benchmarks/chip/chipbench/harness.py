@@ -8,7 +8,15 @@ Everything a cell is made of is found by the names in ``BENCHMARK.json``:
 * ``drivers/<kind>.py``         the driver of that kind of traffic;
 * ``limits/<workload>.json``    the limits of the numbers ``correct`` compares;
 * ``metrics/<name>.py``         one reader per per-layer metric;
-* ``refs/<reference>.py``       the configuration's plain reference.
+* ``refs/<reference>.py``       the configuration's plain reference;
+* ``archs/<model_type>.py``     what the yardstick knows of a model type:
+  its widths and layer groups, its operation counts, its weight rules.
+
+A metric may be split by cells: ``<metric>.<part>``
+(``train_tokens_per_s.mesh`` beside ``train_tokens_per_s``), so that the
+cells of each part carry a bound or a ``moves`` of their own. Where it has
+no reader or driver value of its own, it is ``<metric>``'s, read in its own
+cells.
 
 An unknown name is an error, never a default. A driver builds the program,
 warms it up, runs the timed window inside ``Session.window``, reads device
@@ -61,6 +69,34 @@ def load_module(kind: str, name: str) -> ModuleType:
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+_ARCHS: Dict[Tuple[Path, str], ModuleType] = {}
+
+
+def arch(model_type: str) -> ModuleType:
+    """``archs/<model_type>.py``, loaded once."""
+    key = (HERE, model_type)
+    if key not in _ARCHS:
+        _ARCHS[key] = load_module("archs", model_type)
+    return _ARCHS[key]
+
+
+def reader(name: str) -> ModuleType:
+    """The reader of per-layer metric ``name``: ``metrics/<name>.py``, or,
+    for a part of a split metric without one, its metric's."""
+    base = name.rsplit(".", 1)[0]
+    if (not (HERE / "metrics" / f"{name}.py").is_file()
+            and (HERE / "metrics" / f"{base}.py").is_file()):
+        name = base
+    return load_module("metrics", name)
+
+
+def driver_value(values: Dict[str, float], name: str) -> float:
+    """End-to-end metric ``name`` among a driver's values; a part of a
+    split metric is its metric's value."""
+    return float(values[name] if name in values
+                 else values[name.rsplit(".", 1)[0]])
 
 
 def applies(entry: Dict, workload: str) -> bool:
@@ -366,7 +402,7 @@ def per_layer(session: Session, outcome: Outcome, keep: Optional[str]
                chips=session.cell.chips)
     out: Dict[str, Dict] = {}
     for m in session.cell.per_layer:
-        value = load_module("metrics", m["name"]).read(run)
+        value = reader(m["name"]).read(run)
         if value is not None:
             out[m["name"]] = {"value": float(value), "unit": m["unit"]}
     breakdown = {"device_ops": red["device_ops"], "idle_gaps": red["idle_gaps"]}
@@ -380,7 +416,7 @@ def run_cell(args: argparse.Namespace, t_process: float,
     cell = Cell.find(args.workload)
     driver = load_module("drivers", cell.traffic["kind"])
     for m in cell.per_layer:                   # fail early on a missing one
-        load_module("metrics", m["name"])
+        reader(m["name"])
     import jax
     devices = jax.devices()
     if require_tpu and (devices[0].platform != "tpu"
@@ -404,7 +440,8 @@ def run_cell(args: argparse.Namespace, t_process: float,
         if s.trace:
             metrics, breakdown, busy = per_layer(s, out, args.keep_trace)
         else:
-            metrics = {m["name"]: {"value": float(out.end_to_end[m["name"]]),
+            metrics = {m["name"]: {"value": driver_value(out.end_to_end,
+                                                         m["name"]),
                                    "unit": m["unit"]}
                        for m in cell.end_to_end if m["name"] != "setup_s"}
             metrics["setup_s"] = {"value": s.setup_s, "unit": "s"}

@@ -2,13 +2,15 @@
 numbers a training cell compares.
 
 The reference regenerates the weights and batches from the seed and takes
-the loss and its gradient in float32 at full precision on the device. The
-optimizer, decoupled-weight-decay Adam with global-norm clipping and a
-warm-up plus cosine schedule as the traffic file states it, runs on the
-device one leaf at a time; between steps its moments wait on the host
-(weights, gradients and both moments of a whole model do not fit the chip
-together). The weights' change is taken against their start regenerated
-from the seed, so no copy of the start is kept.
+the loss and its gradient in float32 at full precision on the devices: the
+cell's chips each hold the whole weights and take an equal share of the
+batch's rows, and their gradients are summed, which is the batch's mean
+loss and gradient. The optimizer, decoupled-weight-decay Adam with
+global-norm clipping and a warm-up plus cosine schedule as the traffic file
+states it, runs on the devices one leaf at a time; between steps its
+moments wait on the host (weights, gradients and both moments of a whole
+model do not fit a chip together). The weights' change is taken against
+their start regenerated from the seed, so no copy of the start is kept.
 
 Readings, on both sides:
 
@@ -21,13 +23,16 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Dict
+from typing import Callable, Dict, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from chipbench import data, weights
+
+ROWS = "rows"
 
 
 def lr_at(opt: Dict, step: int) -> float:
@@ -59,21 +64,43 @@ def _norm(a):
     return jnp.sqrt(jnp.sum(jnp.square(a)))
 
 
+def grad_fn(loss_fn: Callable, treedef, mesh: Mesh) -> Callable:
+    """``f(leaves, tokens, labels)`` -> (the batch's mean loss, the mean
+    gradient's leaves): the rows split over the mesh's devices, the weights
+    whole on each; the gradient is summed across the devices."""
+    tree = lambda leaves: jax.tree_util.tree_unflatten(treedef, leaves)
+    whole = NamedSharding(mesh, P())
+    rows = NamedSharding(mesh, P(ROWS))
+    return jax.jit(jax.value_and_grad(lambda p, t, l: loss_fn(tree(p), t, l)),
+                   in_shardings=(whole, rows, rows),
+                   out_shardings=(whole, whole))
+
+
 def run(ref, cfg: Dict, seed: int, batch: int, seq: int, opt: Dict,
-        prec: str = "f32", steps: int = 3) -> Dict:
-    """The reference's readings over the first ``steps`` steps."""
-    get = weights.leaf_fn(seed)
+        prec: str = "f32", steps: int = 3, devices: Sequence = ()) -> Dict:
+    """The reference's readings over the first ``steps`` steps, its rows
+    split over ``devices`` (the first device where none are given)."""
+    devices = list(devices) or jax.devices()[:1]
+    if batch % len(devices):
+        raise ValueError(f"a batch of {batch} rows does not split over "
+                         f"{len(devices)} devices")
+    mesh = Mesh(np.asarray(devices), (ROWS,))
+    whole = NamedSharding(mesh, P())
+    rows = NamedSharding(mesh, P(ROWS))
+    get = weights.leaf_fn(seed, cfg["model_type"], sharding=whole)
     flat, treedef = jax.tree_util.tree_flatten(ref.init(get, cfg))
     keyed = lambda leaves: ref.keys(jax.tree_util.tree_unflatten(treedef,
                                                                  leaves))
-    grad_fn = jax.jit(jax.value_and_grad(
-        lambda p, t, l: ref.loss(p, t, l, cfg, prec)))
+    grads = grad_fn(lambda p, t, l: ref.loss(p, t, l, cfg, prec), treedef,
+                    mesh)
+    print(f"[ref] loss and gradient over {len(devices)} device(s), "
+          f"{batch // len(devices)} rows each", flush=True)
     m = v = None
     losses, grad = [], None
     for step in range(1, steps + 1):
         b = data.train_batch(seed, step - 1, batch, seq, cfg["vocab_size"])
-        loss, g = grad_fn(jax.tree_util.tree_unflatten(treedef, flat),
-                          b["tokens"], b["labels"])
+        loss, g = grads(flat, jax.device_put(b["tokens"], rows),
+                        jax.device_put(b["labels"], rows))
         losses.append(float(loss))
         g = jax.tree_util.tree_leaves(g)
         gnorm = math.sqrt(float(_sumsq(g)))
@@ -83,8 +110,11 @@ def run(ref, cfg: Dict, seed: int, batch: int, seq: int, opt: Dict,
             1 - opt["b2"] ** step, opt["eps"], opt["weight_decay"])]
         first, next_m, next_v = [], [], []
         for i in range(len(flat)):
-            mi = jnp.zeros_like(flat[i]) if m is None else jax.device_put(m[i])
-            vi = jnp.zeros_like(flat[i]) if v is None else jax.device_put(v[i])
+            if m is None:
+                mi = jnp.zeros(flat[i].shape, jnp.float32, device=whole)
+                vi = jnp.zeros(flat[i].shape, jnp.float32, device=whole)
+            else:
+                mi, vi = jax.device_put((m[i], v[i]), whole)
             flat[i], mi, vi = _adamw_leaf(flat[i], g[i], mi, vi, *hyper)
             g[i] = None
             if step == 1:

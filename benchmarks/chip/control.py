@@ -10,16 +10,22 @@ its numbers are compared with the float32 reference, as ``correct`` does:
 the largest of these is a limit's lower reading. ``--control-seeds`` reads
 the control in the program's place: the reference computed with float8
 matmul operands, one step below the bfloat16 the configurations state.
-``--faults`` plants, on training cells, half of the batch left out (the
-mean taken over the rest) in the program's step. The smallest control or
-fault reading is a limit's upper one. Serving cells run a short window of
-``--seconds`` at the cell's own load on each seed.
+``--faults`` plants, on training cells, faults in the reference put in the
+program's place, at the cell's own size and on its chips: half of the batch
+left out (the mean taken over the rest), and, where the cell has more than
+one chip, the exchange between chips left out (each chip takes the loss
+and gradient of its own rows, nothing is summed, the first chip's answer
+is read). The smallest control or fault reading is a limit's upper one.
+Serving cells run a short window of ``--seconds`` at the cell's own load on
+each seed. A training seed not named in ``--seeds`` runs references alone.
 """
 import time
 
 T_PROCESS = time.monotonic()
 
 import argparse  # noqa: E402
+import contextlib  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -37,16 +43,9 @@ def seeds(text: str):
     return [int(x) for x in text.split(",") if x]
 
 
-def train_readings(drv, s: Session, half: bool = False):
+def train_readings(drv, s: Session):
     import jax
     trainer, state, ckpt_dir = drv.build(s)
-    if half:
-        step = trainer._step
-
-        def half_step(state, batch):
-            n = batch["tokens"].shape[0] // 2
-            return step(state, {k: v[:n] for k, v in batch.items()})
-        trainer._step = half_step
     try:
         state, prog = drv.program_readings(s, trainer, state)
         jax.block_until_ready(state)
@@ -59,10 +58,49 @@ def train_readings(drv, s: Session, half: bool = False):
     return prog
 
 
+def half_batch(sound, loss_fn, treedef, mesh):
+    """The reference's loss and gradient over the first half of the rows."""
+    import jax
+    full = sound(loss_fn, treedef, mesh)
+
+    def fn(flat, tokens, labels):
+        half = lambda a: jax.device_put(a[:a.shape[0] // 2], a.sharding)
+        return full(flat, half(tokens), half(labels))
+    return fn
+
+
+def no_exchange(sound, loss_fn, treedef, mesh):
+    """Each device's loss and gradient of its own rows, nothing summed
+    across devices; what is read back is the first device's."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+    from chipbench.trainref import ROWS
+    tree = lambda leaves: jax.tree_util.tree_unflatten(treedef, leaves)
+    local = jax.value_and_grad(lambda p, t, l: loss_fn(tree(p), t, l))
+    return jax.jit(jax.shard_map(local, mesh=mesh,
+                                 in_specs=(P(), P(ROWS), P(ROWS)),
+                                 out_specs=(P(), P()), check_vma=False))
+
+
+@contextlib.contextmanager
+def planted(fault):
+    """``fault`` in place of the reference's loss and gradient."""
+    from chipbench import trainref
+    sound = trainref.grad_fn
+    trainref.grad_fn = functools.partial(fault, sound)
+    try:
+        yield
+    finally:
+        trainref.grad_fn = sound
+
+
 def train_cell(c: Cell, args) -> dict:
     from chipbench import trainref
     drv = load_module("drivers", "train")
-    out = {"program": {}, "control": {}, "half_batch": {}}
+    faults = {"half_batch": half_batch}
+    if c.chips > 1:
+        faults["no_exchange"] = no_exchange
+    out = {"program": {}, "control": {}, **{k: {} for k in faults}}
     mk = lambda seed: Session(c, seed, args.seconds, False, T_PROCESS,
                               __import__("jax").devices())
 
@@ -73,18 +111,21 @@ def train_cell(c: Cell, args) -> dict:
         out[kind][seed] = gaps
         print(kind, seed, gaps, flush=True)
 
-    for seed in args.seeds:
+    every = list(dict.fromkeys(args.seeds + args.control_seeds + args.faults))
+    for seed in every:
         s = mk(seed)
-        prog = train_readings(drv, s)
+        prog = train_readings(drv, s) if seed in args.seeds else None
         ref = drv.reference(s)
-        keep("program", seed, trainref.compare(prog, ref))
-        print("  widest leaves", trainref.widest(prog, ref), flush=True)
+        if prog is not None:
+            keep("program", seed, trainref.compare(prog, ref))
+            print("  widest leaves", trainref.widest(prog, ref), flush=True)
         if seed in args.control_seeds:
             keep("control", seed,
                  trainref.compare(drv.reference(s, "fp8"), ref))
         if seed in args.faults:
-            keep("half_batch", seed, trainref.compare(
-                train_readings(drv, s, half=True), ref))
+            for kind, fault in faults.items():
+                with planted(fault):
+                    keep(kind, seed, trainref.compare(drv.reference(s), ref))
     return out
 
 
@@ -117,7 +158,8 @@ def serve_cell(c: Cell, args) -> dict:
             seqs = [jax.numpy.asarray(np.concatenate(
                 [r.prompt, np.asarray(r.out[:-1], np.int32)])) for r in pick]
             rows = [(len(r.prompt) - 1, len(r.out)) for r in pick]
-            get = weights.leaf_fn(seed, jax.numpy.bfloat16)
+            get = weights.leaf_fn(seed, c.config["model_type"],
+                                  jax.numpy.bfloat16)
             f32 = ref.served_logits(get, c.config, seqs, rows, "f32",
                                     c.traffic["ctx"])
             f8 = ref.served_logits(get, c.config, seqs, rows, "fp8",

@@ -45,7 +45,8 @@ def build(s: Session):
     tr = s.cell.traffic
     pcfg = program.model_config(s.cell.config)
     bb = Backbone(pcfg, param_dtype=jnp.bfloat16, remat=False)
-    params = weights.program_tree(bb.param_specs(), s.seed, jnp.bfloat16)
+    params = weights.program_tree(bb.param_specs(), s.seed,
+                                  s.cell.config["model_type"], jnp.bfloat16)
     return bb, params, Server(bb, params, slots=tr["slots"], ctx=tr["ctx"])
 
 
@@ -131,7 +132,7 @@ def served_gap(s: Session, pick, prec: str) -> float:
                                                              np.int32)]))
             for r in pick]
     rows = [(len(r.prompt) - 1, len(r.out)) for r in pick]
-    get = weights.leaf_fn(s.seed, jnp.bfloat16)
+    get = weights.leaf_fn(s.seed, cfg["model_type"], jnp.bfloat16)
     logits = ref.served_logits(get, cfg, seqs, rows, prec, tr["ctx"])
     worst = 0.0
     for r, lg in zip(pick, logits):

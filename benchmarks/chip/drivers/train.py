@@ -59,7 +59,7 @@ def _per_layer_norms(tree, scale: float = 1.0) -> Dict[str, float]:
     return out
 
 
-def _change_norms(params, seed: int) -> Dict[str, float]:
+def _change_norms(params, seed: int, model_type: str) -> Dict[str, float]:
     """Norm of each leaf's change from its seeded start, one leaf at a time
     so the start never sits on the device whole."""
     key = weights.base_key(seed)
@@ -70,12 +70,13 @@ def _change_norms(params, seed: int) -> Dict[str, float]:
         @jax.jit
         def fn(a, key, p=p):
             if p.startswith("g"):
-                start = jax.vmap(lambda l: weights.leaf(key, p, l, a.shape[1:])
-                                 )(jnp.arange(a.shape[0]))
+                start = jax.vmap(lambda l: weights.leaf(
+                    key, model_type, p, l, a.shape[1:]))(jnp.arange(a.shape[0]))
                 d = a.astype(jnp.float32) - start
                 return jnp.sqrt(jnp.sum(jnp.square(d), axis=tuple(
                     range(1, a.ndim))))
-            d = a.astype(jnp.float32) - weights.leaf(key, p, 0, a.shape)
+            d = a.astype(jnp.float32) - weights.leaf(key, model_type, p, 0,
+                                                     a.shape)
             return jnp.sqrt(jnp.sum(jnp.square(d)))
 
         n = np.asarray(fn(a, key))
@@ -98,8 +99,16 @@ class _Loss:
 
 
 def build(s: Session):
-    """The trainer and its state, from the seed (shared with the control)."""
+    """The trainer and its state, from the seed (shared with the control).
+
+    Where the configuration states a ``mesh`` (axis name -> size, e.g.
+    ``{"data": 2, "model": 2}``), the program is built for it as the
+    program's launcher builds it: its backbone wired for the mesh, its
+    parameters, optimizer state and batch laid out by the program's
+    sharding rules."""
     from repro.data.pipeline import DataConfig
+    from repro.launch.mesh import make_mesh
+    from repro.launch.shardings import sharded_backbone, train_state_shardings
     from repro.models import Backbone
     from repro.optim import adamw
     from repro.runtime.steps import StepSettings
@@ -108,17 +117,26 @@ def build(s: Session):
     tr, cfg = s.cell.traffic, s.cell.config
     pcfg = program.model_config(cfg)
     settings = StepSettings()
-    bb = Backbone(pcfg, remat=settings.remat)
+    mesh = params_sh = state_sh = None
+    if "mesh" in cfg:
+        mesh = make_mesh(tuple(cfg["mesh"].values()), tuple(cfg["mesh"]))
+        bb, params_sh = sharded_backbone(pcfg, mesh, tr["batch"], settings)
+        state_sh = train_state_shardings(params_sh, mesh, settings)
+    else:
+        bb = Backbone(pcfg, remat=settings.remat)
     ckpt_dir = tempfile.mkdtemp(prefix="bench_ckpt_")
     trainer = Trainer(
         bb, adamw.AdamWConfig(**tr["adamw"]),
         DataConfig(vocab=pcfg.vocab, seq_len=tr["seq"],
                    global_batch=tr["batch"], seed=s.seed),
         TrainerConfig(total_steps=0, ckpt_every=10 ** 12, log_every=10 ** 12,
-                      ckpt_dir=ckpt_dir, keep_ckpts=1), settings)
-    params = weights.program_tree(bb.param_specs(), s.seed, jnp.float32)
+                      ckpt_dir=ckpt_dir, keep_ckpts=1), settings,
+        mesh=mesh, state_shardings=state_sh)
+    params = weights.program_tree(bb.param_specs(), s.seed, cfg["model_type"],
+                                  jnp.float32, params_sh)
+    laid = {} if state_sh is None else {"out_shardings": state_sh}
     state = jax.jit(lambda p: {"params": p, "opt": adamw.init_state(p)},
-                    donate_argnums=0)(params)
+                    donate_argnums=0, **laid)(params)
     trainer.start_step = 0
     trainer.store.commit_step(None, None, 0)
     return trainer, state, ckpt_dir
@@ -137,16 +155,19 @@ def program_readings(s: Session, trainer, state):
     state = advance(trainer, state, 1)
     grad = _per_layer_norms(state["opt"]["m"], 1.0 / (1.0 - b1))
     state = advance(trainer, state, SETUP_STEPS - 1)
-    change = _change_norms(state["params"], s.seed)
+    change = _change_norms(state["params"], s.seed,
+                           s.cell.config["model_type"])
     losses = [m["loss"] for m in trainer.metrics_log[:SETUP_STEPS]]
     return state, {"loss": losses, "grad": grad, "change": change}
 
 
 def reference(s: Session, prec: str = "f32") -> Dict:
+    """The reference's readings, its rows split over the cell's chips."""
     tr, cfg = s.cell.traffic, s.cell.config
     ref = load_module("refs", cfg["reference"])
     return trainref.run(ref, cfg, s.seed, tr["batch"], tr["seq"],
-                        tr["adamw"], prec, SETUP_STEPS)
+                        tr["adamw"], prec, SETUP_STEPS,
+                        s.devices[:s.cell.chips])
 
 
 class StoreWatch:

@@ -175,7 +175,7 @@ def test_serving_control_reads_worse_than_the_program():
     seqs = [jnp.asarray(rng.integers(0, 512, 40, dtype=np.int32))
             for _ in range(3)]
     rows = [(8, 32)] * 3
-    get = weights.leaf_fn(29, jnp.bfloat16)
+    get = weights.leaf_fn(29, "qwen3", jnp.bfloat16)
     f32 = ref.served_logits(get, c.config, seqs, rows, "f32", 48)
     f8 = ref.served_logits(get, c.config, seqs, rows, "fp8", 48)
     gap = max(float((np.asarray(a).max(-1) - np.take_along_axis(

@@ -1,13 +1,18 @@
-"""The operation and byte counts against hand counts at tiny shapes, and
-the parameter count against the program's own tree."""
+"""The operation and byte counts against hand counts at tiny shapes, the
+parameter count against the program's own tree, and the counts of the
+benchmark's configurations against those recorded before each model
+type's counts moved into ``archs/``."""
+import json
 import os
 import sys
 
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+CHIP = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, CHIP)
 
 from chipbench import flops  # noqa: E402
+from chipbench.harness import BenchError  # noqa: E402
 
 QWEN = {"model_type": "qwen3", "hidden_size": 4, "num_attention_heads": 2,
         "num_key_value_heads": 1, "head_dim": 2, "intermediate_size": 8,
@@ -15,7 +20,7 @@ QWEN = {"model_type": "qwen3", "hidden_size": 4, "num_attention_heads": 2,
 RWKV = {"model_type": "rwkv6", "hidden_size": 4, "num_attention_heads": 2,
         "head_size": 2, "intermediate_size": 8, "vocab_size": 10,
         "num_hidden_layers": 2, "time_mix_extra_dim": 3,
-        "time_decay_extra_dim": 5}
+        "time_decay_extra_dim": 5, "tie_word_embeddings": False}
 
 
 def test_qwen3_layer_and_model_matmul_weights():
@@ -76,3 +81,50 @@ def test_param_count_matches_the_programs_tree():
                 num_key_value_heads=2, head_dim=16, intermediate_size=96,
                 vocab_size=128)
     assert flops.param_count(mine) == n
+
+
+def test_rwkv6_param_count_by_hand():
+    # the layer's matmul weights, 9 vectors of D, 3 of Dr = 4; the
+    # embedding and the head (untied), the final norm
+    layer = flops.layer_matmul_params(RWKV) + 9 * 4 + 3 * 4
+    assert flops.param_count(RWKV) == 2 * layer + 2 * 10 * 4 + 4
+
+
+# Recorded from the counts of chipbench/flops.py as it was before the model
+# types' counts moved into archs/: (batch, seq) -> train_step_flops;
+# (tokens, pairs) -> serve_flops; decode_step_bytes over contexts 128,
+# 1000, 1152; None where the count did not exist for the model type.
+RECORDED = {
+    "qwen3-4b-L5": {
+        "train": [11109453004800.0, 5554726502400.0, 44437812019200.0],
+        "params": 893612800,
+        "serve": [1873050337280.0, 1910046720.0], "decode": 1833920000},
+    "qwen3-4b": {
+        "train": [50355203211264.0, 25177601605632.0, 201420812845056.0],
+        "params": 4022468096,
+        "serve": [8547152691200.0, 8929280000.0], "decode": 8381135872},
+    "rwkv6-3b-L4": {
+        "train": [6333734584320.0, 3166867292160.0, 25334938337280.0],
+        "params": None, "serve": [1050924810240.0, 1026293760.0],
+        "decode": None},
+}
+
+
+@pytest.mark.parametrize("name", sorted(RECORDED))
+def test_counts_of_the_configurations_are_those_recorded(name):
+    with open(os.path.join(CHIP, "configs", f"{name}.json")) as f:
+        cfg = json.load(f)
+    want = RECORDED[name]
+    assert [flops.train_step_flops(cfg, b, s) for b, s in
+            ((4, 512), (2, 512), (16, 512))] == want["train"]
+    assert [flops.serve_flops(cfg, t, p) for t, p in
+            ((1024, flops.causal_pairs(1024)), (1, 1500))] == want["serve"]
+    if want["params"] is not None:
+        assert flops.param_count(cfg) == want["params"]
+        assert flops.decode_step_bytes(cfg, [128, 1000, 1152]) == \
+            want["decode"]
+
+
+def test_an_unknown_model_type_names_the_missing_file():
+    with pytest.raises(BenchError, match="archs/lfm2_moe.py"):
+        flops.train_step_flops(dict(QWEN, model_type="lfm2_moe"), 1, 8)

@@ -26,7 +26,7 @@ def test_every_cell_finds_its_files_and_readers():
     b = bench()
     for w in b["workloads"]:
         c = Cell.find(w["name"], b)
-        assert c.config["model_type"] in ("qwen3", "rwkv6")
+        assert harness.arch(c.config["model_type"]).WIDTHS
         harness.load_module("drivers", c.traffic["kind"])
         harness.load_module("refs", c.config["reference"])
         assert c.limits and all(v > 0 for v in c.limits.values())
@@ -34,8 +34,21 @@ def test_every_cell_finds_its_files_and_readers():
         assert "setup_s" in names and len(names) >= 2
         assert c.per_layer
         for m in c.per_layer:
-            assert callable(harness.load_module("metrics", m["name"]).read)
+            assert callable(harness.reader(m["name"]).read)
             assert m["moves"] in names
+
+
+def test_a_part_of_a_split_metric_reads_as_its_metric():
+    """``<metric>.<part>`` without a reader or driver value of its own is
+    ``<metric>``'s; with one, its own; with neither, refused."""
+    assert harness.reader("mfu.train.mesh").__file__ == \
+        harness.reader("mfu.train").__file__
+    assert harness.reader("mfu.train").__file__.endswith("mfu.train.py")
+    values = {"train_tokens_per_s": 5.0, "setup_s.x": 2.0}
+    assert harness.driver_value(values, "train_tokens_per_s.mesh") == 5.0
+    assert harness.driver_value(values, "setup_s.x") == 2.0
+    with pytest.raises(BenchError):
+        harness.reader("no_such_metric.mesh")
 
 
 @pytest.mark.parametrize("kind,name", [("drivers", "nope"),
@@ -58,9 +71,71 @@ def test_unknown_workload_config_or_mix_is_refused():
         Cell.find("x.bad-mix", dict(b, workloads=b["workloads"] + [w]))
 
 
+# A model type the benchmark has never seen: two layer groups of the
+# program's ``moe`` FFN (a stacked 3-D expert leaf), one attention layer,
+# then a period of (attention, windowed attention).
+TOY_ARCH = """
+from chipbench import flops, weights
+
+WIDTHS = {"hidden_size": "d_model", "num_attention_heads": "n_heads",
+          "num_key_value_heads": "n_kv_heads", "head_dim": "hd",
+          "moe_intermediate_size": "moe_d_ff", "num_experts": "n_experts",
+          "num_experts_per_tok": "top_k", "vocab_size": "vocab"}
+
+WEIGHTS = {name: weights.norm for name in ("ln1", "ln2", "q_norm", "k_norm")}
+
+
+def groups(cfg):
+    from repro.models.config import LayerGroup
+    first = cfg["num_dense_layers"]
+    return (LayerGroup(("attn",), first),
+            LayerGroup(("attn", "local"), (cfg["num_hidden_layers"] - first)
+                       // 2))
+
+
+def _attn(cfg):
+    D, H, KV, hd = (cfg["hidden_size"], cfg["num_attention_heads"],
+                    cfg["num_key_value_heads"], cfg["head_dim"])
+    return D * H * hd + 2 * D * KV * hd + H * hd * D
+
+
+def layer_matmul_params(cfg):
+    D, E, k = cfg["hidden_size"], cfg["num_experts"], \
+        cfg["num_experts_per_tok"]
+    return _attn(cfg) + D * E + k * 3 * D * cfg["moe_intermediate_size"]
+
+
+def param_count(cfg):
+    D, hd, E = cfg["hidden_size"], cfg["head_dim"], cfg["num_experts"]
+    layer = (_attn(cfg) + D * E + E * 3 * D * cfg["moe_intermediate_size"]
+             + 2 * D + 2 * hd)
+    return cfg["num_hidden_layers"] * layer + cfg["vocab_size"] * D + D
+
+
+def attn_flops(cfg, pairs):
+    return (4.0 * cfg["num_attention_heads"] * cfg["head_dim"] * pairs
+            * cfg["num_hidden_layers"])
+
+
+def train_step_flops(cfg, batch, seq):
+    return (6.0 * flops.matmul_params(cfg) * batch * seq
+            + 3.0 * attn_flops(cfg, batch * flops.causal_pairs(seq)))
+"""
+
+TOY_CONFIG = {"model_type": "toy_moe", "program_arch": "toymoe-tiny",
+              "reference": "qwen3", "hidden_size": 64,
+              "num_attention_heads": 4, "num_key_value_heads": 2,
+              "head_dim": 16, "moe_intermediate_size": 32, "num_experts": 4,
+              "num_experts_per_tok": 2, "vocab_size": 512,
+              "num_dense_layers": 1, "num_hidden_layers": 3}
+
+
 def test_new_config_mix_and_metric_need_only_new_files(tmp_path, monkeypatch):
     """A copy of the benchmark gains a configuration, a traffic mix and a
-    per-layer metric by new files and new entries; no file is edited."""
+    per-layer metric by new files and new entries; and a configuration of
+    a model type it has never seen, with two layer groups and a stacked
+    expert leaf, through a new ``archs/`` file, which is then built, drawn
+    and counted. No file is edited."""
     root = tmp_path / "checkout"
     chip = root / "benchmarks" / "chip"
     shutil.copytree(CHIP, chip, ignore=shutil.ignore_patterns(
@@ -101,6 +176,43 @@ def test_new_config_mix_and_metric_need_only_new_files(tmp_path, monkeypatch):
                                                   "setup_s"]
     assert harness.load_module("metrics", "step_ms.train").read({}) == 7.0
     assert harness.load_module("drivers", c.traffic["kind"]).run
+
+    # the new model type: its file, its configuration, a cell on it
+    import dataclasses
+    import jax
+    import numpy as np
+    from chipbench import flops, program, weights
+    from repro.models import Backbone, get_config
+    from repro.models.config import register
+    register(dataclasses.replace(
+        get_config("qwen3-4b"), name="toymoe-tiny", family="moe", d_model=64,
+        n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128, vocab=512,
+        ffn_kind="moe", n_experts=4, top_k=2, moe_d_ff=32, attn_window=8))
+    (chip / "archs" / "toy_moe.py").write_text(TOY_ARCH)
+    (chip / "configs" / "toy-moe.json").write_text(json.dumps(TOY_CONFIG))
+    (chip / "limits" / "toy-moe.train.b8.json").write_text(
+        json.dumps({"loss": 0.02, "grad": 0.01, "change": 0.05}))
+    b["configs"].append(dict(b["configs"][0], name="toy-moe",
+                             file="benchmarks/chip/configs/toy-moe.json"))
+    b["workloads"].append({"name": "toy-moe.train.b8", "config": "toy-moe",
+                           "traffic": "train-b8", "chips": 1, "why": "x"})
+    (root / "BENCHMARK.json").write_text(json.dumps(b))
+    c = Cell.find("toy-moe.train.b8")
+    pcfg = program.model_config(c.config)
+    assert [(g.pattern, g.repeat) for g in pcfg.groups] == [
+        (("attn",), 1), (("attn", "local"), 1)]
+    specs = Backbone(pcfg).param_specs()
+    tree = weights.program_tree(specs, 11, "toy_moe", jax.numpy.float32)
+    expert = tree["g1"]["s1"]["w_gate"]              # [repeat, E, D, Fe]
+    assert expert.shape == (1, 4, 64, 32)
+    assert float(np.std(expert)) == pytest.approx(1 / 8, rel=0.1)
+    n = sum(a.size for a in jax.tree_util.tree_leaves(tree))
+    assert flops.param_count(c.config) == n
+    assert flops.train_step_flops(c.config, 8, 16) == pytest.approx(
+        # attention 3 x 4096, router 64 x 4, 2 of 4 experts routed; head;
+        # score and context over 8 x 136 causal pairs in 3 layers
+        6 * (3 * (12288 + 64 * 4 + 2 * 3 * 64 * 32) + 64 * 512) * 8 * 16
+        + 3 * 4 * 64 * 8 * 136 * 3)
     after = {p: p.read_bytes() for p in chip.rglob("*") if p.is_file()
              and p in before}
     assert after == before

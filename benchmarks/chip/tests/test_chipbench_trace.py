@@ -108,3 +108,54 @@ def test_json_round_trip(tmp_path):
     tracefile.save(small(), p)
     back = Trace.from_json(json.load(open(p)))
     assert tracefile.reduce(back) == tracefile.reduce(small())
+
+
+def collectives() -> Trace:
+    """Two devices; the window is [0, 100] ns. Device 0: a collective under
+    a fusion (hidden), an asynchronous all-gather whose halves show
+    (2 + 10 ns), a synchronous all-reduce inside a while loop (10 ns).
+    Device 1: a reduce-scatter (10 ns), a permute's wait cut by the window
+    (5 ns), one outside the window."""
+    op = lambda name, kind, s, e: (f"%{name} = f32[8] {kind}(%p)", s, e)
+    return Trace(
+        ops={"/device:TPU:0": [
+            op("fusion.1", "fusion", 10, 40),
+            op("all-reduce.7", "all-reduce", 20, 30),
+            op("all-gather-start.1", "all-gather-start", 40, 42),
+            op("fusion.2", "fusion", 42, 60),
+            op("all-gather-done.1", "all-gather-done", 60, 70),
+            op("while.3", "while", 70, 100),
+            op("fusion.4", "fusion", 70, 80),
+            op("all-reduce.5", "all-reduce", 80, 90),
+            op("fusion.6", "fusion", 90, 100)],
+            "/device:TPU:1": [
+            op("fusion.1", "fusion", 0, 50),
+            op("reduce-scatter.2", "reduce-scatter", 50, 60),
+            op("collective-permute-done.3", "collective-permute-done", 95,
+               110),
+            op("all-to-all.4", "all-to-all", 120, 130)]},
+        modules={"/device:TPU:0": [("jit_train_step", 10, 100)],
+                 "/device:TPU:1": [("jit_train_step", 0, 100)]},
+        spans={"/host:CPU/main": [("window", 0, 100), ("step", 0, 95)]})
+
+
+def test_collective_exposed_share_counts_what_no_other_op_hides(capsys):
+    from chipbench.harness import load_module
+    reader = load_module("metrics", "collective_exposed_share")
+    tr = collectives()
+    red = tracefile.reduce(tr)
+    # (22 + 15) / 2 ns of a 100 ns window
+    assert reader.read({"trace": tr, "trace_window": red["window_ns"]}) == \
+        pytest.approx(18.5)
+    assert "all-gather-done" in capsys.readouterr().out
+    # the profiler dropped the window annotation: the host clock's 100 ns
+    # ending where the last device program ended read the same
+    tr.spans["/host:CPU/main"] = [("step", 0, 95)]
+    red = tracefile.reduce(tr, wall_ns=100)
+    assert red["window_from"] == "host_clock"
+    assert reader.read({"trace": tr, "trace_window": red["window_ns"]}) == \
+        pytest.approx(18.5)
+    # a trace with no collective gives nothing
+    tr.ops = {d: [x for x in ops if "fusion" in x[0] or "while" in x[0]]
+              for d, ops in tr.ops.items()}
+    assert reader.read({"trace": tr, "trace_window": (0, 100)}) is None
